@@ -8,7 +8,10 @@ data files under experiments/bench/.
 from __future__ import annotations
 
 import json
+import os
 import pathlib
+import subprocess
+import sys
 import time
 
 import numpy as np
@@ -26,6 +29,38 @@ def save(name: str, obj, quick: bool = False) -> None:
     OUT.mkdir(parents=True, exist_ok=True)
     stem = f"{name}_quick" if quick else name
     (OUT / f"{stem}.json").write_text(json.dumps(obj, indent=1))
+
+
+def cpu_child(module: str, spec: dict, devices: int) -> dict:
+    """Run ``python -m benchmarks.<module> --child <spec>`` on ``devices``
+    forced host-CPU devices and return the JSON of its last stdout line,
+    or ``{"error": ...}``.  Parents that orchestrate CPU children never
+    touch JAX themselves, so on a machine with an accelerator no row of
+    one result lands on the chip while its siblings run on the CPU."""
+    env = dict(os.environ, JAX_PLATFORMS="cpu",
+               XLA_FLAGS=f"--xla_force_host_platform_device_count={devices}",
+               PYTHONPATH=os.pathsep.join(
+                   ["src", os.environ.get("PYTHONPATH", "")]).rstrip(
+                       os.pathsep))
+    child = subprocess.run(
+        [sys.executable, "-m", f"benchmarks.{module}", "--child",
+         json.dumps(spec)],
+        capture_output=True, text=True, env=env, timeout=1200)
+    if child.returncode != 0:              # record, don't hide, failures
+        return {"error": child.stderr[-1000:]}
+    try:
+        return json.loads(child.stdout.strip().splitlines()[-1])
+    except (IndexError, json.JSONDecodeError):
+        return {"error": "no JSON payload on child stdout: "
+                + (child.stdout + child.stderr)[-800:]}
+
+
+def platform() -> dict:
+    """The device a benchmark row ran on, as JAX reports it."""
+    import jax
+    d = jax.devices()[0]
+    return {"platform": d.platform, "device_kind": d.device_kind,
+            "device_count": jax.device_count()}
 
 
 def timed(fn, *args, repeat: int = 3, **kw):

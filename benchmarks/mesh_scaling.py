@@ -15,13 +15,14 @@ alongside for comparison.
 Each multi-device shape times the fused train step under BOTH collective
 strategies (DESIGN.md §10) — ``manual`` (hand-written lax collectives
 over per-device tiles, no operand ever replicated) vs ``gspmd`` (the
-staged reference path, LIVE loss operands replicated on full 2-D meshes)
-— and records the §5.2 per-device live-operand byte model for each.  Two
-RuntimeError guards keep the tentpole claim honest: at (2,2) the manual
-path must be no slower than staged (within measurement tolerance), and
-its operand bytes must scale ~1/(dp·sp) vs the staged path's replicated
-layout.  Compiled memory stats (temp/alias/argument bytes from XLA's
-memory_analysis) are stored per strategy as the measured counterpart.
+GSPMD-partitioned reference path) — and records the §5.2 per-device
+live-operand byte model.  Two RuntimeError guards: at (2,2) the manual
+path must be no slower than gspmd (within measurement tolerance), and its
+operand bytes must scale ~1/(dp·sp) against the single device.  Compiled
+memory stats (temp/alias/argument bytes from XLA's memory_analysis) are
+stored per strategy as the measured counterpart.  Every shape runs in a
+host-CPU child and records the platform it ran on; the parent never
+touches JAX.
 
 Each mesh shape also records PER-COLLECTIVE microbench columns — the
 workload's §5.1/§5.2 communication terms in isolation: the dense layer's
@@ -40,12 +41,9 @@ from __future__ import annotations
 
 import argparse
 import json
-import os
-import subprocess
-import sys
 import time
 
-from .common import save
+from .common import cpu_child, platform, save
 
 MESHES = ((1, 1), (2, 1), (1, 2), (2, 2))
 
@@ -68,16 +66,16 @@ def _collective_times(mesh, params, *, n: int, b: int, k: int = 16,
     import jax
     import jax.numpy as jnp
     from jax import lax
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
     from repro.core.mesh import DATA, GRAPH
+    from repro.sharding.compat import shard_map_nocheck
 
     dp, sp = mesh.shape[DATA], mesh.shape[GRAPH]
     out = {}
 
     def bench(name, fn, in_specs, out_specs, x):
-        f = jax.jit(shard_map(fn, mesh=mesh, in_specs=in_specs,
-                              out_specs=out_specs, check_rep=False))
+        f = jax.jit(shard_map_nocheck(fn, mesh=mesh, in_specs=in_specs,
+                                      out_specs=out_specs))
         f(x).block_until_ready()
         t0 = time.perf_counter()
         for _ in range(repeat):
@@ -193,9 +191,7 @@ def _measure_mesh(dp: int, sp: int, *, n: int, graphs: int, batch: int,
             steps=steps, warm=warm)
         by_coll[strat] = {"s_per_step": train_s, "memory": memory}
         operand[strat] = minibatch_operand_bytes(
-            n, cfg.minibatch, dp, sp,
-            "gspmd" if strat in ("single", "gspmd") else "manual",
-            rep=rep.name)
+            n, cfg.minibatch, dp, sp, rep=rep.name)
         if strat == default:
             replay_dev_bytes = rb
     train_s = by_coll[default]["s_per_step"]
@@ -223,6 +219,7 @@ def _measure_mesh(dp: int, sp: int, *, n: int, graphs: int, batch: int,
     coll = {} if mesh is None else _collective_times(
         mesh, params, n=n, b=solve_batch, k=cfg.embed_dim)
     return {
+        **platform(),
         "train_s_per_step": train_s,
         "train_collectives_default": default,
         "train_by_collectives": by_coll,
@@ -245,29 +242,11 @@ def run(quick: bool = False):
                           "solve_batch": solve_batch, "steps": steps,
                           "minibatch": 32, "embed_dim": 16,
                           "quick": quick, "meshes": list(MESHES)}}
-    child_env = dict(os.environ, JAX_PLATFORMS="cpu",
-                     XLA_FLAGS="--xla_force_host_platform_device_count=4",
-                     PYTHONPATH=os.pathsep.join(
-                         ["src", os.environ.get("PYTHONPATH", "")]).rstrip(
-                             os.pathsep))
     for dp, sp in MESHES:
-        spec = json.dumps({"dp": dp, "sp": sp, "n": n, "graphs": graphs,
-                           "batch": batch, "steps": steps, "warm": warm,
-                           "solve_batch": solve_batch})
-        child = subprocess.run(
-            [sys.executable, "-m", "benchmarks.mesh_scaling",
-             "--child", spec],
-            capture_output=True, text=True, env=child_env, timeout=1200)
-        key = f"{dp}x{sp}"
-        if child.returncode == 0:
-            try:
-                results[key] = json.loads(
-                    child.stdout.strip().splitlines()[-1])
-            except (IndexError, json.JSONDecodeError):
-                results[key] = {"error": "no JSON payload on child stdout: "
-                                + (child.stdout + child.stderr)[-800:]}
-        else:                              # record, don't hide, failures
-            results[key] = {"error": child.stderr[-1000:]}
+        results[f"{dp}x{sp}"] = cpu_child(
+            "mesh_scaling", {"dp": dp, "sp": sp, "n": n, "graphs": graphs,
+                             "batch": batch, "steps": steps, "warm": warm,
+                             "solve_batch": solve_batch}, devices=4)
 
     save("mesh_scaling", results, quick=quick)
     failed = [f"{dp}x{sp}" for dp, sp in MESHES
@@ -279,31 +258,31 @@ def run(quick: bool = False):
             f"mesh shapes {failed} failed — see "
             f"experiments/bench/mesh_scaling.json: "
             + " | ".join(results[k]["error"][-200:] for k in failed))
-    # -- tentpole guards (DESIGN.md §10): the manual-collective path must
-    # actually retire the replication tax on the full 2-D mesh.
+    # -- guards (DESIGN.md §10) on the full 2-D mesh.
     r22 = results["2x2"]["train_by_collectives"]
-    ob22 = results["2x2"]["operand_bytes_per_device"]
     man_s, gsp_s = (r22["manual"]["s_per_step"],
                     r22["gspmd"]["s_per_step"])
     if man_s > gsp_s * 1.10:               # 10% CPU-timer noise allowance
         raise RuntimeError(
-            f"manual collectives slower than staged gspmd at (2,2): "
+            f"manual collectives slower than gspmd at (2,2): "
             f"{man_s*1e3:.1f}ms vs {gsp_s*1e3:.1f}ms per step")
-    # manual keeps (B/dp, N/sp) tiles — §5.2 says ~1/(dp·sp) of the staged
-    # path's replicated live operands; allow slack for the unscaled tuple
-    # scalars (actions/rewards/targets shard only by dp).
-    man_b, gsp_b = ob22["manual"]["total"], ob22["gspmd"]["total"]
-    if man_b > gsp_b * (1.4 / 4):
+    # manual keeps (B/dp, N/sp) tiles — §5.2 says ~1/(dp·sp) of the single
+    # device's live operands; allow slack for the unscaled tuple scalars
+    # (actions/targets shard only by dp).
+    man_b = results["2x2"]["operand_bytes_per_device"]["manual"]["total"]
+    one_b = results["1x1"]["operand_bytes_per_device"]["single"]["total"]
+    if man_b > one_b * (1.4 / 4):
         raise RuntimeError(
             f"manual per-device operand bytes did not scale ~1/(dp*sp) "
-            f"at (2,2): manual {man_b} vs staged {gsp_b}")
+            f"at (2,2): manual {man_b} vs one device {one_b}")
     rows = []
     for dp, sp in MESHES:
         r = results[f"{dp}x{sp}"]
         rows.append((
             f"mesh_{dp}x{sp}",
             r["train_s_per_step"] * 1e6,
-            f"train {r['train_s_per_step']*1e3:.1f}ms/step solve "
+            f"{r['platform']}: train {r['train_s_per_step']*1e3:.1f}ms/step "
+            f"solve "
             f"{r['solve_s']*1e3:.1f}ms state/dev "
             f"{r['state_bytes_per_device']/1024:.1f}KiB replay/dev "
             f"{r['replay_bytes_per_device']/1024:.1f}KiB"))
@@ -314,9 +293,8 @@ def run(quick: bool = False):
                 f"mesh_{dp}x{sp}_strategies",
                 bc["manual"]["s_per_step"] * 1e6,
                 f"manual {bc['manual']['s_per_step']*1e3:.1f}ms/step "
-                f"staged {bc['gspmd']['s_per_step']*1e3:.1f}ms/step "
-                f"operand/dev manual {ob['manual']['total']/1024:.1f}KiB "
-                f"staged {ob['gspmd']['total']/1024:.1f}KiB"))
+                f"gspmd {bc['gspmd']['s_per_step']*1e3:.1f}ms/step "
+                f"operand/dev {ob['manual']['total']/1024:.1f}KiB"))
         coll = r.get("collectives_s_per_call") or {}
         if coll:
             rows.append((

@@ -4,10 +4,11 @@ Measures wall time per RL training step (one act→step→remember→τ×GD cycl
 paper Alg. 5) for the two engines of DESIGN.md §8 at τ ∈ {1, 4} and
 P ∈ {1, 2} spatial devices.  The host loop pays 3+τ host↔device round
 trips per step; the fused jitted step pays one — the gap is the point of
-the device-resident engine.  P=2 runs in a subprocess with
-``--xla_force_host_platform_device_count=2`` (same mechanism as the
-spatial equivalence tests); on this single-CPU container it measures
-collective/partitioning overhead, not real scaling.
+the device-resident engine.  Both P=1 and P=2 run in host-CPU
+subprocesses (P=2 with ``--xla_force_host_platform_device_count=2``, the
+mechanism of the spatial equivalence tests), and each grid records the
+platform it ran on; the P=2 grid measures collective/partitioning
+overhead, not real scaling.
 
 Each grid also records the fused step's compiled-memory footprint with
 and without ``donate_argnums`` (DESIGN.md §10): XLA's memory_analysis
@@ -26,12 +27,9 @@ from __future__ import annotations
 
 import argparse
 import json
-import os
-import subprocess
-import sys
 import time
 
-from .common import save
+from .common import cpu_child, platform, save
 
 TAUS = (1, 4)
 
@@ -162,7 +160,7 @@ def _measure_donation(tau: int, *, n: int, graphs: int,
 
 def _measure_grid(n: int, graphs: int, steps: int, warm: int,
                   spatial: int) -> dict:
-    out = {}
+    out = platform()
     for tau in TAUS:
         host = _measure_engine("host", tau, n=n, graphs=graphs, steps=steps,
                                warm=warm, spatial=spatial)
@@ -182,25 +180,12 @@ def run(quick: bool = False):
 
     results = {"config": {"n": n, "graphs": graphs, "steps": steps,
                           "minibatch": 32, "embed_dim": 16, "taus": TAUS,
-                          "quick": quick},
-               "p1": _measure_grid(n, graphs, steps, warm, spatial=0)}
-
-    # P=2 needs 2 XLA devices → subprocess with a forced host device count.
-    child_env = dict(os.environ, JAX_PLATFORMS="cpu",
-                     XLA_FLAGS="--xla_force_host_platform_device_count=2",
-                     PYTHONPATH=os.pathsep.join(
-                         ["src", os.environ.get("PYTHONPATH", "")]).rstrip(
-                             os.pathsep))
-    spec = json.dumps({"n": n, "graphs": graphs, "steps": steps,
-                       "warm": warm, "spatial": 2})
-    child = subprocess.run(
-        [sys.executable, "-m", "benchmarks.train_step_scaling",
-         "--child", spec],
-        capture_output=True, text=True, env=child_env, timeout=1200)
-    if child.returncode == 0:
-        results["p2"] = json.loads(child.stdout.strip().splitlines()[-1])
-    else:                                  # record, don't hide, P=2 failures
-        results["p2"] = {"error": child.stderr[-1000:]}
+                          "quick": quick}}
+    for pname, spatial in (("p1", 0), ("p2", 2)):
+        results[pname] = cpu_child(
+            "train_step_scaling", {"n": n, "graphs": graphs, "steps": steps,
+                                   "warm": warm, "spatial": spatial},
+            devices=max(spatial, 1))
 
     save("train_step_scaling", results, quick=quick)
     rows = []
@@ -208,7 +193,7 @@ def run(quick: bool = False):
         grid = results[pname]
         if "error" in grid:
             rows.append((f"train_step_{pname}", float("nan"),
-                         "P=2 subprocess failed"))
+                         f"{pname} subprocess failed"))
             continue
         for tau in TAUS:
             r = grid[f"tau{tau}"]
@@ -217,7 +202,7 @@ def run(quick: bool = False):
                 r["fused_s_per_step"] * 1e6,
                 f"host {r['host_s_per_step']*1e3:.1f}ms/step fused "
                 f"{r['fused_s_per_step']*1e3:.1f}ms/step "
-                f"speedup {r['speedup']:.2f}x"))
+                f"speedup {r['speedup']:.2f}x on {grid['platform']}"))
         don = grid.get("donation") or {}
         if don:
             rows.append((

@@ -13,6 +13,7 @@ import argparse
 
 import numpy as np
 
+from repro.compile_cache import setup_compile_cache
 from repro.core import (Agent, PolicyConfig, train_agent, evaluate_quality,
                         parse_spatial, solve)
 from repro.core import env as env_lib
@@ -64,6 +65,7 @@ def main():
                          "`python -m repro.launch.solve_serve --ckpt-dir` "
                          "or GraphSolverService.from_checkpoint)")
     args = ap.parse_args()
+    setup_compile_cache()
 
     kw = {"er": {"rho": 0.15}, "ba": {"d": 4}, "social": {}}[args.kind]
     train = random_graph_batch(args.kind, args.nodes, args.graphs, seed=0,

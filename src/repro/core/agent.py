@@ -47,14 +47,19 @@ def greedy_action_state(params: PolicyParams, state, *, rep: GraphRep,
     return jnp.argmax(s, axis=-1), s
 
 
+def max_q_from_scores(scores: jax.Array, candidate: jax.Array):
+    """max_v Q(s', v) from masked scores, with the no-candidate
+    convention (0)."""
+    return jnp.where(candidate.sum(-1) > 0, scores.max(-1), 0.0)
+
+
 def max_q_raw(params: PolicyParams, state, *, rep: GraphRep,
               num_layers: int, kernel: str = "fused", compute: str = "f32"):
-    """max_v Q(s', v) with the no-candidate convention (0) — un-jitted so
-    the fused train step (``repro.core.engine``) can trace it inline."""
+    """max_v Q(s', v) of a state — un-jitted so callers can trace it
+    inline."""
     s = rep.scores(params, state, num_layers=num_layers, kernel=kernel,
                    compute=compute)
-    has_cand = state.candidate.sum(-1) > 0
-    return jnp.where(has_cand, s.max(-1), 0.0)
+    return max_q_from_scores(s, state.candidate)
 
 
 max_q_state = functools.partial(
@@ -79,13 +84,18 @@ def max_q(params: PolicyParams, adj, sol, cand, *, num_layers: int):
 def train_minibatch_raw(params: PolicyParams, opt: AdamState, state,
                         action, target, *, rep: GraphRep, num_layers: int,
                         lr: float, kernel: str = "fused",
-                        compute: str = "f32"):
+                        compute: str = "f32", score=None):
     """One GD iteration on a re-materialized minibatch (Alg. 5 lines 19-23).
     Un-jitted building block shared by the host path (jitted below), the
-    fused train step's scan body and the spatial shard_map path."""
+    fused train step's scan body and the spatial shard_map path.
+    ``score(params, state, masked)`` replaces ``rep.scores`` where the
+    scores must be taken per device (``engine.per_graph_scorer``)."""
     def loss_fn(p):
-        s = rep.scores(p, state, num_layers=num_layers, masked=False,
-                       kernel=kernel, compute=compute)
+        if score is not None:
+            s = score(p, state, masked=False)
+        else:
+            s = rep.scores(p, state, num_layers=num_layers, masked=False,
+                           kernel=kernel, compute=compute)
         qsa = jnp.take_along_axis(s, action[:, None], axis=-1)[:, 0]
         return jnp.mean(jnp.square(qsa - target))
 

@@ -49,10 +49,10 @@ import jax.numpy as jnp
 from jax import lax
 
 from . import env as env_lib
-from .agent import max_q_raw, train_minibatch_raw
+from .agent import max_q_from_scores, train_minibatch_raw
 from .graphrep import GraphRep, get_rep
 from .inference import apply_selection
-from .mesh import (MeshSpec, constrain_batch, constrain_dataset,
+from .mesh import (DATA, MeshSpec, constrain_batch, constrain_dataset,
                    constrain_replay, make_mesh, normalize_spatial,
                    resolve_collectives, shard_replay)
 from .policy import PolicyConfig, PolicyParams
@@ -60,6 +60,35 @@ from .qmodel import NEG_INF
 from .replay import (DeviceReplay, device_replay_init, device_replay_push,
                      device_replay_sample)
 from ..optim import AdamState
+
+
+def per_graph_scorer(mesh, rep: GraphRep, *, num_layers: int, kernel: str,
+                     compute: str):
+    """``rep.scores`` for the phases of a mesh step whose node rows stay
+    whole (acting, TD targets, csr scoring and GD): each device scores
+    its own B/dp graphs under shard_map over ``data``.  The arithmetic is
+    per graph, so the scores — and their gradients — equal the one-device
+    call, and the Pallas layer kernel, which GSPMD cannot partition, runs
+    per device.  A batch that does not divide dp is scored whole on every
+    device."""
+    from jax.sharding import PartitionSpec as P
+    from ..sharding.compat import shard_map_nocheck
+
+    def score(params, state, masked=True):
+        def local(p, st):
+            return rep.scores(p, st, num_layers=num_layers, masked=masked,
+                              kernel=kernel, compute=compute)
+
+        if mesh is None:
+            return local(params, state)
+        b = state.candidate.shape[0]
+        spec = P(DATA) if b % mesh.shape[DATA] == 0 else P()
+        return shard_map_nocheck(
+            local, mesh=mesh,
+            in_specs=(P(), jax.tree.map(lambda _: spec, state)),
+            out_specs=spec)(params, state)
+
+    return score
 
 
 @jax.tree_util.register_dataclass
@@ -129,7 +158,6 @@ def get_train_step(cfg: PolicyConfig, *,
                    rep: Union[str, GraphRep, None] = None,
                    problem: str = "mvc", tau: Optional[int] = None,
                    target_mode: str = "fresh", explore: bool = True,
-                   stage_boundary: Optional[str] = None,
                    donate: bool = True):
     """Build (and cache) the fused jitted train step for a configuration.
 
@@ -149,10 +177,8 @@ def get_train_step(cfg: PolicyConfig, *,
     ``"manual"`` (the ``auto`` default on dp>1 ∧ sp>1) runs the
     manual-collective path — the replay ring is sampled by exchanging row
     tiles over ``data`` and topology is re-materialized per tile, so no
-    loss operand is ever replicated; ``"gspmd"`` runs the staged
-    reference path.  ``stage_boundary`` overrides the gspmd path's
-    staging scope (``spatial.STAGE_SCOPES``; the canary test's hook —
-    ``None`` = the scope the mesh shape needs).
+    loss operand is ever replicated; ``"gspmd"`` runs the GSPMD-partitioned
+    reference path.
 
     ``donate=True`` donates the engine carry AND the episode state to the
     step (``donate_argnums``): replay ring, params, opt and state buffers
@@ -169,13 +195,12 @@ def get_train_step(cfg: PolicyConfig, *,
         raise ValueError(f"minibatch {cfg.minibatch} not divisible by the "
                          f"data-axis size {dp} of mesh spec {cfg.spatial!r}")
     return _build_train_step(cfg, rep, problem, tau, target_mode, explore,
-                             stage_boundary, donate)
+                             donate)
 
 
 @functools.lru_cache(maxsize=64)
 def _build_train_step(cfg: PolicyConfig, rep: GraphRep, problem: str,
                       tau: int, target_mode: str, explore: bool,
-                      stage_boundary: Optional[str] = None,
                       donate: bool = True):
     step_fn = env_lib.make(problem)
     residual = env_lib.residual_mode(problem)
@@ -198,11 +223,13 @@ def _build_train_step(cfg: PolicyConfig, rep: GraphRep, problem: str,
                     "the plain data-parallel step, which never replicates "
                     "an operand — leave collectives='auto'")
             # data-parallel only (sp == 1 guaranteed above): the plain
-            # minibatch step runs under GSPMD with the batch constrained
-            # over `data` — no shard_map retiling of ragged edge rows.
-            gd_step = functools.partial(train_minibatch_raw, rep=rep,
-                                        num_layers=num_layers, lr=lr,
-                                        kernel=kernel, compute=compute)
+            # minibatch step, its scores taken per graph over `data` — no
+            # shard_map retiling of ragged edge rows.
+            gd_step = functools.partial(
+                train_minibatch_raw, rep=rep, num_layers=num_layers, lr=lr,
+                kernel=kernel, compute=compute,
+                score=per_graph_scorer(mesh, rep, num_layers=num_layers,
+                                       kernel=kernel, compute=compute))
         else:
             coll = resolve_collectives(cfg.collectives, dp, sp)
             if coll == "manual" and cand_fn is not None:
@@ -228,13 +255,14 @@ def _build_train_step(cfg: PolicyConfig, rep: GraphRep, problem: str,
                 from .spatial import spatial_train_minibatch_fn
                 gd_step = spatial_train_minibatch_fn(
                     mesh, num_layers=num_layers, lr=lr, jit=False,
-                    kernel=kernel, compute=compute,
-                    stage_boundary=stage_boundary)
+                    kernel=kernel, compute=compute)
     else:
         mesh = None
         gd_step = functools.partial(train_minibatch_raw, rep=rep,
                                     num_layers=num_layers, lr=lr,
                                     kernel=kernel, compute=compute)
+    score = per_graph_scorer(mesh, rep, num_layers=num_layers,
+                             kernel=kernel, compute=compute)
 
     def _epsilon(step_count):
         frac = jnp.minimum(1.0, step_count.astype(jnp.float32)
@@ -260,8 +288,7 @@ def _build_train_step(cfg: PolicyConfig, rep: GraphRep, problem: str,
         rng, k_eps, k_pick, k_train = jax.random.split(es.rng, 4)
 
         # -- act (Alg. 1 lines 9-10) --------------------------------------
-        scores = rep.scores(es.params, state, num_layers=num_layers,
-                            kernel=kernel, compute=compute)
+        scores = score(es.params, state)
         action = jnp.argmax(scores, axis=-1)
         if explore:
             logits = jnp.where(state.candidate > 0.5, 0.0, NEG_INF)
@@ -275,9 +302,8 @@ def _build_train_step(cfg: PolicyConfig, rep: GraphRep, problem: str,
 
         # -- remember (Alg. 5 lines 11-13) --------------------------------
         if stored:
-            nxt = max_q_raw(es.params, new_state, rep=rep,
-                            num_layers=num_layers, kernel=kernel,
-                            compute=compute)
+            nxt = max_q_from_scores(score(es.params, new_state),
+                                    new_state.candidate)
             target = reward + gamma * nxt * (1.0 - done.astype(jnp.float32))
         else:
             target = jnp.zeros_like(reward)
@@ -308,9 +334,8 @@ def _build_train_step(cfg: PolicyConfig, rep: GraphRep, problem: str,
                     st2 = rep.state_from_tuples(source, gi, sol2,
                                                 residual=residual,
                                                 candidate_fn=cand_fn)
-                    nxt = max_q_raw(params, st2, rep=rep,
-                                    num_layers=num_layers, kernel=kernel,
-                                    compute=compute)
+                    nxt = max_q_from_scores(score(params, st2),
+                                            st2.candidate)
                     tgt = rew + gamma * nxt * (1.0 - dn)
                 st = rep.state_from_tuples(source, gi, sol,
                                            residual=residual,
@@ -386,10 +411,9 @@ def _build_solve_step(rep: GraphRep, problem: str, num_layers: int,
         mesh = make_mesh(dp, sp)
         if rep.name == "csr":
             # data-parallel only (sp == 1 guaranteed above): plain scoring
-            # under GSPMD with the batch constrained over `data`.
-            def score_fn(params, state):
-                return rep.scores(params, state, num_layers=num_layers,
-                                  kernel=kernel, compute=compute)
+            # with the batch over `data`.
+            score_fn = per_graph_scorer(mesh, rep, num_layers=num_layers,
+                                        kernel=kernel, compute=compute)
         else:
             from .spatial import spatial_solve_scores_fn
             score_fn = spatial_solve_scores_fn(

@@ -111,10 +111,9 @@ def mesh_shape(mesh: jax.sharding.Mesh) -> Tuple[int, int]:
 # ---------------------------------------------------------------------------
 
 # "manual": hand-written lax.all_gather/psum/psum_scatter over per-device
-# (B/dp, N/sp, ·) tiles — no operand is ever replicated, sidestepping the
-# upstream GSPMD mispartitioning by construction.  "gspmd": the staged
-# reference path (LIVE loss operands replicated at the shard_map boundary
-# on dp>1 ∧ sp>1 meshes).  "auto" resolves per mesh shape.
+# (B/dp, N/sp, ·) tiles — no operand is ever replicated.  "gspmd": the
+# reference path, where GSPMD partitions the assembled minibatch onto the
+# shard_map tiling.  "auto" resolves per mesh shape.
 COLLECTIVES_MODES = ("auto", "manual", "gspmd")
 
 
@@ -127,8 +126,7 @@ def check_collectives(mode: str) -> str:
 
 def resolve_collectives(mode: str, dp: int, sp: int) -> str:
     """Config value → concrete strategy for a (dp, sp) mesh.  ``auto``
-    picks manual exactly where the GSPMD replication tax bites (full 2-D
-    meshes); 1-D meshes run unstaged under GSPMD and are already exact."""
+    picks manual on full 2-D meshes and gspmd on 1-D meshes."""
     check_collectives(mode)
     if mode != "auto":
         return mode
@@ -294,31 +292,22 @@ def sparse_per_device_bytes(n: int, max_deg: int, b: int, p: int,
 
 
 def minibatch_operand_bytes(n: int, minibatch: int, dp: int, sp: int,
-                            collectives: str, rep: str = "dense",
+                            rep: str = "dense",
                             max_deg: Optional[int] = None) -> dict:
     """Per-device LIVE bytes of the GD loss operands inside one spatial GD
-    iteration — the §5.2 term the manual-collective path shrinks.
-
-    Under ``collectives="manual"`` every operand is a tile: topology
-    (M/dp, N/sp, ·), solution/candidate (M/dp, N/sp), action/target
-    (M/dp,) — bytes fall ~1/(dp·sp) with the mesh.  Under ``"gspmd"`` on a
-    full 2-D mesh (dp>1 ∧ sp>1) the staged reference path replicates the
-    LIVE operands (topology, solution, action, target) on every device —
-    the replication tax; only the candidate mask stays tiled.  1-D meshes
-    run unstaged under gspmd and keep the tiled layout."""
-    staged = collectives == "gspmd" and dp > 1 and sp > 1
-    ddp, dsp = (1, 1) if staged else (dp, sp)
+    iteration (§5.2): every operand is a tile — topology (M/dp, N/sp, ·),
+    solution/candidate (M/dp, N/sp), action/target (M/dp,) — so bytes
+    fall ~1/(dp·sp) with the mesh under either collectives strategy."""
     if rep == "dense":
-        topo = 4.0 * minibatch * n * n / (ddp * dsp)
+        topo = 4.0 * minibatch * n * n / (dp * sp)
     else:
         d = max_deg if max_deg else n
-        topo = 5.0 * minibatch * n * d / (ddp * dsp)
+        topo = 5.0 * minibatch * n * d / (dp * sp)
     out = {
         "topology": topo,
-        "solution": 4.0 * minibatch * n / (ddp * dsp),
-        # candidate is dead in the GD loss and never staged — tiled always
+        "solution": 4.0 * minibatch * n / (dp * sp),
         "candidate": 4.0 * minibatch * n / (dp * sp),
-        "tuples": 2 * 4.0 * minibatch / ddp,        # action + target
+        "tuples": 2 * 4.0 * minibatch / dp,         # action + target
     }
     out["total"] = sum(out.values())
     return out

@@ -56,10 +56,9 @@ class PolicyConfig:
     compute: str = "f32"
     # Cross-shard communication strategy of the mesh GD step (DESIGN.md
     # §10): "manual" = hand-written lax collectives over per-device tiles
-    # (no operand ever replicated); "gspmd" = the staged reference path
-    # (LIVE loss operands replicated at the shard_map boundary on full 2-D
-    # meshes to dodge the upstream GSPMD mispartitioning); "auto" =
-    # manual on dp>1 ∧ sp>1 meshes, gspmd otherwise.
+    # (no operand ever replicated); "gspmd" = the GSPMD-partitioned
+    # reference path; "auto" = manual on dp>1 ∧ sp>1 meshes, gspmd
+    # otherwise.
     collectives: str = "auto"
 
     def __post_init__(self):

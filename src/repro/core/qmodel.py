@@ -12,6 +12,8 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
+from .s2v import f32_matmuls
+
 NEG_INF = -1e9
 
 
@@ -37,6 +39,7 @@ def init_q(key: jax.Array, k: int, scale: float = 0.1) -> QParams:
     )
 
 
+@f32_matmuls
 def scores_local(
     params: QParams,
     embed_local: jax.Array,     # (B, K, Nl)

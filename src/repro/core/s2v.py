@@ -12,11 +12,12 @@ Kernel selection (``kernel=``, DESIGN.md §12):
   → residual add → ReLU — as the Pallas super-kernel on TPU
   (``repro.kernels.s2v_fused``, wrapped in a custom_vjp whose backward runs
   the jnp composition) and as the equivalent single XLA composition
-  elsewhere.  The fused path also elides layer 0 entirely: embeddings
-  initialize to zero (Alg. 2 line 3), so the first aggregation is exactly
-  zero and layer 1 reduces to relu(embed1 + embed2) — bit-identical, half
-  the aggregation work at L=2, and one collective fewer per eval when
-  sharded.
+  elsewhere, or on TPU where the size rule :func:`s2v_kernel_fits` finds
+  the kernel too large for VMEM.  The fused path also elides layer 0
+  entirely: embeddings initialize to zero (Alg. 2 line 3), so the first
+  aggregation is exactly zero and layer 1 reduces to relu(embed1 +
+  embed2) — bit-identical, half the aggregation work at L=2, and one
+  collective fewer per eval when sharded.
 - ``"xla"``: the reference per-op chain, kept for parity tests and as the
   semantics of record.
 
@@ -33,6 +34,11 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 from jax import lax
+
+from ..kernels.backend import VMEM_LIMIT_BYTES, on_tpu
+from ..kernels.s2v_csr import csr_vmem_bytes
+from ..kernels.s2v_fused import dense_vmem_bytes
+from ..kernels.s2v_gather import sparse_vmem_bytes
 
 KERNELS = ("fused", "xla")
 COMPUTE_MODES = {"f32": jnp.float32, "bf16": jnp.bfloat16}
@@ -51,6 +57,51 @@ def check_kernel(kernel: str) -> str:
     if kernel not in KERNELS:
         raise ValueError(f"unknown kernel {kernel!r}; available: {KERNELS}")
     return kernel
+
+
+def f32_matmuls(fn):
+    """Trace ``fn``'s matmuls at full f32 precision.
+
+    XLA's DEFAULT precision on TPU rounds f32 matmul operands to bf16;
+    ``compute="f32"`` promises f32 operands, so the S2V and Q functions
+    run under the "float32" setting.  bf16-cast operands stay bf16, and
+    CPU matmuls are f32 either way."""
+    @functools.wraps(fn)
+    def wrapped(*args, **kwargs):
+        with jax.default_matmul_precision("float32"):
+            return fn(*args, **kwargs)
+    return wrapped
+
+
+def s2v_kernel_fits(rep: str, *, k: int, n: int = 0, max_degree: int = 0,
+                    compute_dtype=jnp.float32,
+                    aggregate_only: bool = False) -> bool:
+    """The S2V size rule (ROADMAP S2(a)): True iff the Pallas layer kernel
+    of ``rep`` fits the scoped-VMEM budget ``VMEM_LIMIT_BYTES`` at these
+    shapes.  Dense and sparse kernels are tiled, so only K and the max
+    degree D move their footprint; the CSR kernel holds whole (K, N)
+    panels, so its bound is on N.  ``aggregate_only`` selects the
+    aggregation-only kernels (dense ``mp_aggregate``, the sparse gather)."""
+    epilogue = not aggregate_only
+    if rep == "dense":
+        need = dense_vmem_bytes(k, epilogue=epilogue,
+                                compute_dtype=compute_dtype)
+    elif rep == "sparse":
+        need = sparse_vmem_bytes(k, max_degree, epilogue=epilogue,
+                                 compute_dtype=compute_dtype)
+    elif rep == "csr":
+        need = csr_vmem_bytes(k, n, compute_dtype=compute_dtype)
+    else:
+        raise ValueError(f"unknown graph rep {rep!r}")
+    return need <= VMEM_LIMIT_BYTES
+
+
+def s2v_layer_impl(rep: str, **shapes) -> str:
+    """Which lowering a fused S2V layer takes: ``"pallas"`` (the kernel)
+    on a TPU where :func:`s2v_kernel_fits`, else ``"xla"`` (the same layer
+    as one XLA composition)."""
+    return ("pallas" if on_tpu() and s2v_kernel_fits(rep, **shapes)
+            else "xla")
 
 
 @jax.tree_util.register_dataclass
@@ -84,6 +135,7 @@ def init_s2v(key: jax.Array, k: int, scale: float = 0.1) -> S2VParams:
 # recomputed ReLU mask matches the forward up to compute-dtype rounding).
 # ---------------------------------------------------------------------------
 
+@f32_matmuls
 def _dense_layer_jnp(theta4, embed, adj, base, cd):
     """relu(base + θ4 @ (embed @ adj)) with cd-cast matmul operands and
     f32 accumulation — the XLA lowering of the fused layer."""
@@ -115,14 +167,16 @@ _dense_layer_hw.defvjp(_dense_layer_hw_fwd, _dense_layer_hw_bwd)
 
 
 def _dense_layer_fused(theta4, embed, adj, base, cd):
-    """Backend dispatch for one fused dense layer: the Pallas super-kernel
-    on TPU, the jnp composition elsewhere (XLA's native fusion beats the
-    interpret-mode kernel off-TPU — same policy as the sparse gather)."""
-    if jax.default_backend() == "tpu":
+    """Dispatch for one fused dense layer by :func:`s2v_layer_impl`: the
+    Pallas super-kernel on TPU, the jnp composition elsewhere (XLA's
+    native fusion beats the interpret-mode kernel off-TPU)."""
+    if s2v_layer_impl("dense", k=embed.shape[1], compute_dtype=cd) \
+            == "pallas":
         return _dense_layer_hw(theta4, embed, adj, base, cd)
     return _dense_layer_jnp(theta4, embed, adj, base, cd)
 
 
+@f32_matmuls
 def _agg_jnp(embed, adj, cd):
     return jnp.einsum("bkl,bln->bkn", embed.astype(cd), adj.astype(cd),
                       preferred_element_type=jnp.float32)
@@ -149,11 +203,13 @@ _agg_hw.defvjp(_agg_hw_fwd, _agg_hw_bwd)
 def _aggregate_fused(embed, adj, cd):
     """Aggregation-only partial (sharded dense path: the psum between
     aggregate and epilogue splits the fusion at the collective)."""
-    if jax.default_backend() == "tpu":
+    if s2v_layer_impl("dense", k=embed.shape[1], compute_dtype=cd,
+                      aggregate_only=True) == "pallas":
         return _agg_hw(embed, adj, cd)
     return _agg_jnp(embed, adj, cd)
 
 
+@f32_matmuls
 def embed_local(
     params: S2VParams,
     adj_local: jax.Array,       # (B, Nl, N) local rows of residual adjacency

@@ -17,9 +17,10 @@ endpoints; per-edge factors are derived from the partial-solution mask S
 
 ``kernel="fused"`` (default) runs each layer as ONE launch — gather →
 weight → segment-sum → θ4-matmul → residual add → ReLU — via the Pallas
-edge-tiled kernel ``repro.kernels.s2v_csr.fused_s2v_layer_csr`` on TPU and
-the equivalent single XLA composition elsewhere, with the same layer-0
-elision as the other two backends (embed⁰ = 0 ⇒ layer 1 is
+edge-tiled kernel ``repro.kernels.s2v_csr.fused_s2v_layer_csr`` on TPU
+(up to the N where the size rule ``repro.core.s2v.s2v_kernel_fits``
+admits it) and the equivalent single XLA composition elsewhere, with the
+same layer-0 elision as the other two backends (embed⁰ = 0 ⇒ layer 1 is
 relu(embed1+embed2), bit-identical).  ``kernel="xla"`` is the reference
 per-op chain.  ``compute="bf16"`` casts gather/matmul operands to bf16
 with f32 accumulation (DESIGN.md §12); the segment-sum scatter always
@@ -44,7 +45,8 @@ from .graphs import (CsrGraphBatch, CsrGraphState, csr_batch_from_dense,
                      csr_row_ids, csr_segment_sum)
 from .policy import PolicyParams
 from .qmodel import scores_local
-from .s2v import check_kernel, compute_dtype
+from .s2v import (check_kernel, compute_dtype, f32_matmuls,
+                  s2v_layer_impl)
 
 __all__ = ["CsrGraphBatch", "csr_batch_from_dense", "csr_edge_factors",
            "embed_csr", "embed_csr_local", "csr_policy_scores",
@@ -88,6 +90,7 @@ def _segment_rows(weighted: jax.Array, row_ids: jax.Array,
     return jax.vmap(one)(weighted, row_ids)
 
 
+@f32_matmuls
 def _csr_layer_jnp(theta4, x_full, indices, row_ids, edge_w, base, cd):
     """One fused CSR layer as a single XLA composition: gather edge columns
     with cd-cast operands, weight, segment-sum into rows with f32
@@ -129,15 +132,18 @@ _csr_layer_hw.defvjp(_csr_layer_hw_fwd, _csr_layer_hw_bwd)
 
 
 def _csr_layer_fused(theta4, x_full, indices, row_ids, edge_w, base, cd):
-    """Backend dispatch for one fused CSR layer: the Pallas edge-tiled
-    kernel on TPU, the jnp composition elsewhere (same policy as the other
-    two backends)."""
-    if jax.default_backend() == "tpu":
+    """Dispatch for one fused CSR layer by the size rule
+    (:func:`repro.core.s2v.s2v_layer_impl`): the Pallas edge-tiled kernel
+    on TPU while its whole-(K, N) buffers fit VMEM, the jnp segment-sum
+    composition otherwise."""
+    if s2v_layer_impl("csr", k=x_full.shape[1], n=x_full.shape[2],
+                      compute_dtype=cd) == "pallas":
         return _csr_layer_hw(theta4, x_full, indices, row_ids, edge_w,
                              base, cd)
     return _csr_layer_jnp(theta4, x_full, indices, row_ids, edge_w, base, cd)
 
 
+@f32_matmuls
 def embed_csr_local(params, indices: jax.Array, row_ids: jax.Array,
                     edge_w: jax.Array, sol: jax.Array, *, num_layers: int,
                     kernel: str = "fused", compute: str = "f32") -> jax.Array:

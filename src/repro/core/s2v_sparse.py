@@ -18,7 +18,8 @@ replaces the dense path's all-reduce.
 
 ``kernel="fused"`` (default) runs each layer as ONE fused launch —
 gather/aggregate → θ4-matmul → residual add → ReLU — via the Pallas
-super-kernel ``repro.kernels.s2v_fused.fused_s2v_layer_sparse`` on TPU and
+super-kernel ``repro.kernels.s2v_fused.fused_s2v_layer_sparse`` on TPU
+(where the size rule ``repro.core.s2v.s2v_kernel_fits`` admits it) and
 the equivalent single XLA composition elsewhere, and elides layer 0
 entirely (zero-initialized embeddings make the first aggregation exactly
 zero, so layer 1 is relu(embed1+embed2) — bit-identical, and one
@@ -47,7 +48,8 @@ from .graphs import (SparseGraphBatch, SparseGraphState,
                      sparse_batch_from_dense)
 from .policy import PolicyParams
 from .qmodel import scores_local, NEG_INF
-from .s2v import check_kernel, compute_dtype
+from .s2v import (check_kernel, compute_dtype, f32_matmuls,
+                  s2v_layer_impl)
 
 __all__ = ["SparseGraphBatch", "sparse_batch_from_dense", "embed_sparse",
            "embed_sparse_local", "residual_edge_factors",
@@ -142,6 +144,7 @@ def _gather_neighbors(x: jax.Array, nbrs: jax.Array) -> jax.Array:
     return jax.vmap(lambda xb, nb: xb[:, nb])(x, nbrs)
 
 
+@f32_matmuls
 def _gather_aggregate(xp: jax.Array, nbrs: jax.Array,
                       edge: jax.Array) -> jax.Array:
     """Reference aggregation: Σ_d xp[b,k,nbrs[b,i,d]]·edge[b,i,d] → (B,K,Nl).
@@ -151,17 +154,20 @@ def _gather_aggregate(xp: jax.Array, nbrs: jax.Array,
     return jnp.einsum("bknd,bnd->bkn", gathered, edge)
 
 
-def _default_gather_impl() -> Optional[Callable]:
+def _default_gather_impl(k: int, max_degree: int) -> Optional[Callable]:
     """Aggregation hot loop of the reference "xla" chain: the Pallas gather
-    kernel on TPU (VMEM-tiled, avoids materializing the (B, K, N, D)
-    gather transient in HBM); pure-jnp gather elsewhere, where XLA's fused
-    gather beats the interpret-mode kernel."""
-    if jax.default_backend() == "tpu":
+    kernel on TPU where the size rule admits it (VMEM-tiled, avoids
+    materializing the (B, K, N, D) gather transient in HBM); pure-jnp
+    gather elsewhere, where XLA's fused gather beats the interpret-mode
+    kernel."""
+    if s2v_layer_impl("sparse", k=k, max_degree=max_degree,
+                      aggregate_only=True) == "pallas":
         from ..kernels.ops import sparse_mp_aggregate
         return sparse_mp_aggregate
     return None
 
 
+@f32_matmuls
 def _sparse_layer_jnp(theta4, x_full, nbr_local, edge_local, base, cd):
     """One fused sparse layer as a single XLA composition: gather/aggregate
     with cd-cast operands and f32 accumulation, θ4-matmul, residual + ReLU.
@@ -199,14 +205,18 @@ _sparse_layer_hw.defvjp(_sparse_layer_hw_fwd, _sparse_layer_hw_bwd)
 
 
 def _sparse_layer_fused(theta4, x_full, nbr_local, edge_local, base, cd):
-    """Backend dispatch for one fused sparse layer: the Pallas super-kernel
-    on TPU, the jnp composition elsewhere (same policy as the gather)."""
-    if jax.default_backend() == "tpu":
+    """Dispatch for one fused sparse layer by the size rule
+    (:func:`repro.core.s2v.s2v_layer_impl`): the Pallas super-kernel on
+    TPU, the jnp composition elsewhere (same policy as the gather)."""
+    if s2v_layer_impl("sparse", k=x_full.shape[1],
+                      max_degree=nbr_local.shape[2],
+                      compute_dtype=cd) == "pallas":
         return _sparse_layer_hw(theta4, x_full, nbr_local, edge_local,
                                 base, cd)
     return _sparse_layer_jnp(theta4, x_full, nbr_local, edge_local, base, cd)
 
 
+@f32_matmuls
 def embed_sparse_local(params, nbr_local: jax.Array, edge_local: jax.Array,
                        sol_local: jax.Array, *, num_layers: int,
                        axis: Optional[str] = None,
@@ -228,7 +238,8 @@ def embed_sparse_local(params, nbr_local: jax.Array, edge_local: jax.Array,
     cd = compute_dtype(compute)
     b, nl, d = nbr_local.shape
     k = params.theta1.shape[0]
-    agg = gather_impl or _default_gather_impl() or _gather_aggregate
+    agg = (gather_impl or _default_gather_impl(k, d)
+           or _gather_aggregate)
 
     deg = edge_local.sum(-1)                                # residual degree
     embed1 = params.theta1[None, :, None] * sol_local[:, None, :]

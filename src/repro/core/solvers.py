@@ -25,23 +25,28 @@ def greedy_mvc_batch(adj_batch: np.ndarray) -> np.ndarray:
 
     One vectorized argmax/row-zeroing step per round serves the WHOLE
     batch; rounds run until every graph is edge-free (max cover size over
-    B rounds instead of a Python loop per graph).  Per graph this picks the
-    exact same node sequence as the sequential heuristic (np.argmax
-    first-max tie-breaking on each row), so results are bit-identical to
-    mapping :func:`greedy_mvc` over the batch.
+    B rounds instead of a Python loop per graph).  Degrees are kept up to
+    date by subtracting the picked node's column, O(N) per round instead
+    of a fresh O(N²) row sum, with the same exact integer-valued counts.
+    Per graph this picks the exact same node sequence as the sequential
+    heuristic (np.argmax first-max tie-breaking on each row), so results
+    are bit-identical to mapping :func:`greedy_mvc` over the batch.
     """
     a = np.asarray(adj_batch, np.float32).copy()
     b, n, _ = a.shape
     sol = np.zeros((b, n), bool)
-    active = a.reshape(b, -1).sum(-1) > 0
+    deg = a.sum(-1)                           # (B, N) row sums
+    active = deg.sum(-1) > 0
     while active.any():
-        deg = a.sum(-1)                       # (B, N)
         v = deg.argmax(-1)                    # (B,) first max per graph
         act = np.flatnonzero(active)
-        sol[act, v[act]] = True
-        a[act, v[act], :] = 0
-        a[act, :, v[act]] = 0
-        active = a.reshape(b, -1).sum(-1) > 0
+        va = v[act]
+        sol[act, va] = True
+        deg[act] -= a[act, :, va]             # column v leaves every row
+        deg[act, va] = 0.0                    # row v is zeroed
+        a[act, va, :] = 0
+        a[act, :, va] = 0
+        active = deg.sum(-1) > 0
     return sol
 
 

@@ -30,7 +30,6 @@ layout, so every pre-mesh caller keeps working unchanged.
 """
 from __future__ import annotations
 
-import dataclasses
 import functools
 from typing import Optional
 
@@ -170,27 +169,6 @@ def spatial_solve_scores_fn(mesh: jax.sharding.Mesh, *, num_layers: int,
                                         state.candidate)
 
 
-# Staging scopes for the gspmd REFERENCE path's workaround (DESIGN.md
-# §10): which minibatch operands get replicated at the shard_map boundary
-# on full 2-D (dp>1 ∧ sp>1) meshes.  "live" (the default) stages exactly
-# the operands that are LIVE in the GD loss — topology, solution, action,
-# target; the candidate mask is dead there (training scores run
-# masked=False) and leave-one-out measurement shows it is the ONLY operand
-# that can stay partitioned without resurfacing the mispartitioning.
-# "all" is the PR 4 behavior (entire minibatch, candidate included);
-# "none" disables the workaround — used by the canary test that watches
-# the upstream jax bug.
-#
-# DEPRECATED as a tuning surface: the default train path on full 2-D
-# meshes is now ``manual_train_minibatch_fn`` (collectives="manual"),
-# which never replicates an operand and needs no staging.  These scopes
-# remain only for the ``collectives="gspmd"`` reference path and the
-# canary (explicit ``stage_boundary=`` plumbing through
-# ``engine.get_train_step`` — the old ``_STAGE_OVERRIDE`` module global is
-# gone).
-STAGE_SCOPES = ("live", "all", "none")
-
-
 def _ownership_loss(s_l, action, target, my, nl, dp):
     """Squared TD error of the locally-owned (batch row, action node)
     terms, normalized by the GLOBAL minibatch size (local rows × dp) so
@@ -205,8 +183,7 @@ def _ownership_loss(s_l, action, target, my, nl, dp):
 
 def spatial_train_minibatch_fn(mesh: jax.sharding.Mesh, *,
                                num_layers: int, lr: float, jit: bool = True,
-                               kernel: str = "fused", compute: str = "f32",
-                               stage_boundary: Optional[str] = None):
+                               kernel: str = "fused", compute: str = "f32"):
     """Build the mesh-parallel GD step (paper Alg. 5's per-GPU gradient
     descent + MPI_All_reduce, generalized to the 2-D mesh; DESIGN.md
     §8/§10) — the GSPMD-partitioned REFERENCE path
@@ -284,40 +261,9 @@ def spatial_train_minibatch_fn(mesh: jax.sharding.Mesh, *,
 
     built = {}
 
-    # Boundary staging: on the full 2-D mesh (dp>1 ∧ sp>1 ONLY), minibatch
-    # operands produced by in-jit gathers (replay sample → Tuples2Graphs)
-    # and fed straight into shard_map get mispartitioned by GSPMD on the
-    # JAX versions this repo supports (observed on 0.4.x CPU: wrong
-    # operand slices, order-1e-3 loss/param errors — see the canary in
-    # tests/test_mesh.py).  Staging the loss's LIVE operands replicated at
-    # the shard_map boundary restores exactness; the in_specs still tile
-    # all GD compute per device.  Per-operand leave-one-out measurement
-    # (DESIGN.md §10): topology, solution, action and target are each
-    # individually required; the candidate mask — dead in the GD loss
-    # (masked=False scores) — is the only operand that can keep its
-    # partitioned layout.  1-D meshes are unaffected and keep the fully
-    # partitioned operand layout (per-device minibatch memory stays
-    # O(1/P), §5.2).
-    if stage_boundary is not None and stage_boundary not in STAGE_SCOPES:
-        raise ValueError(f"stage_boundary must be one of {STAGE_SCOPES} "
-                         f"or None, got {stage_boundary!r}")
-    scope = stage_boundary
-    if scope is None:
-        scope = "live" if dp > 1 and mesh.shape[GRAPH] > 1 else "none"
-    _stage_sharding = jax.sharding.NamedSharding(mesh, P())
-
-    def _stage(x):
-        return jax.lax.with_sharding_constraint(x, _stage_sharding)
-
     def fn(params, opt, state, action, target):
         _check_divisible(mesh, state.candidate.shape[0],
                          state.candidate.shape[1], "spatial GD")
-        if scope in ("all", "live"):
-            staged = {f: _stage(getattr(state, f))
-                      for f in state_field_specs(state)
-                      if scope == "all" or f != "candidate"}
-            state = dataclasses.replace(state, **staged)
-            action, target = _stage(action), _stage(target)
         if isinstance(state, SparseGraphState):
             key = ("sparse", state.residual)
             if key not in built:
@@ -346,8 +292,8 @@ def manual_train_minibatch_fn(mesh: jax.sharding.Mesh, *, rep,
     """Build the MANUAL-COLLECTIVE mesh GD step (``collectives="manual"``,
     DESIGN.md §10) — the default on full 2-D meshes.
 
-    Unlike the staged gspmd reference path, no operand is ever
-    replicated: the replay ring stays resident in its (R/dp, N/sp, ·)
+    Unlike the gspmd reference path, which hands GSPMD the assembled
+    minibatch to partition, no operand is ever replicated: the replay ring stays resident in its (R/dp, N/sp, ·)
     tiling, the minibatch sample indices are the ONLY replicated input
     (4·B bytes), and everything the math needs from remote shards moves
     through hand-written lax collectives:

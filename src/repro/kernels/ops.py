@@ -1,8 +1,8 @@
 """Jit'd public wrappers around the Pallas kernels.
 
-``interpret`` defaults to True on CPU (this container) and False on TPU,
-selected once at import from the backend.  All wrappers accept/return the
-same shapes as their ``ref.py`` oracles.
+``interpret=None`` compiles on TPU and interprets on every other backend
+(``backend.resolve_interpret``).  All wrappers accept/return the same
+shapes as their ``ref.py`` oracles.
 """
 from __future__ import annotations
 
@@ -12,7 +12,7 @@ import jax
 import jax.numpy as jnp
 
 from . import ref as _ref
-from .backend import default_interpret as _default_interpret
+from .backend import resolve_interpret
 from .s2v_fused import (fused_s2v_layer as _fused_s2v_layer,
                         fused_s2v_layer_sparse as _fused_s2v_layer_sparse,
                         mp_aggregate as _mp_aggregate)
@@ -29,7 +29,6 @@ def fused_s2v_layer(theta4, embed, adj, base, *, tile_n: int = 128,
                     tile_l: int = 128, compute_dtype=jnp.float32,
                     interpret: bool | None = None):
     """Fused dense structure2vec layer (Alg. 2 lines 11+13-14, one launch)."""
-    interpret = _default_interpret() if interpret is None else interpret
     return _fused_s2v_layer(theta4, embed, adj, base, tile_n=tile_n,
                             tile_l=tile_l, compute_dtype=compute_dtype,
                             interpret=interpret)
@@ -41,7 +40,6 @@ def fused_s2v_layer_sparse(theta4, x, neighbors, edge, base, *,
                            tile_n: int = 128, compute_dtype=jnp.float32,
                            interpret: bool | None = None):
     """Fused sparse (padded edge-list) structure2vec layer, one launch."""
-    interpret = _default_interpret() if interpret is None else interpret
     return _fused_s2v_layer_sparse(theta4, x, neighbors, edge, base,
                                    tile_n=tile_n, compute_dtype=compute_dtype,
                                    interpret=interpret)
@@ -50,10 +48,9 @@ def fused_s2v_layer_sparse(theta4, x, neighbors, edge, base, *,
 @functools.partial(jax.jit, static_argnames=("tile_e", "compute_dtype",
                                              "interpret"))
 def fused_s2v_layer_csr(theta4, x, indices, row_ids, edge_w, base, *,
-                        tile_e: int = 512, compute_dtype=jnp.float32,
+                        tile_e: int = 256, compute_dtype=jnp.float32,
                         interpret: bool | None = None):
     """Fused CSR (flat edge-array) structure2vec layer, one launch."""
-    interpret = _default_interpret() if interpret is None else interpret
     return _fused_s2v_layer_csr(theta4, x, indices, row_ids, edge_w, base,
                                 tile_e=tile_e, compute_dtype=compute_dtype,
                                 interpret=interpret)
@@ -65,7 +62,6 @@ def mp_aggregate(embed, adj, *, tile_n: int = 128, tile_l: int = 128,
                  compute_dtype=jnp.float32, interpret: bool | None = None):
     """Aggregation-only partial kernel for the sharded dense path (the psum
     between aggregate and epilogue splits the fusion at the collective)."""
-    interpret = _default_interpret() if interpret is None else interpret
     return _mp_aggregate(embed, adj, tile_n=tile_n, tile_l=tile_l,
                          compute_dtype=compute_dtype, interpret=interpret)
 
@@ -74,7 +70,6 @@ def mp_aggregate(embed, adj, *, tile_n: int = 128, tile_l: int = 128,
 def sparse_mp_aggregate(x, neighbors, edge, *, tile_n: int = 128,
                         interpret: bool | None = None):
     """Sparse (padded edge-list) s2v neighbor aggregation (gather kernel)."""
-    interpret = _default_interpret() if interpret is None else interpret
     return _sparse_mp_aggregate(x, neighbors, edge, tile_n=tile_n,
                                 interpret=interpret)
 
@@ -82,7 +77,7 @@ def sparse_mp_aggregate(x, neighbors, edge, *, tile_n: int = 128,
 @functools.partial(jax.jit, static_argnames=("chunk", "interpret"))
 def wkv6(r, k, v, w, u, *, chunk: int = 64, interpret: bool | None = None):
     """Chunked RWKV6 recurrence. Returns (out, final_state)."""
-    interpret = _default_interpret() if interpret is None else interpret
+    interpret = resolve_interpret(interpret)
     return _wkv6_chunked(r, k, v, w, u, chunk=chunk, interpret=interpret)
 
 
@@ -91,7 +86,7 @@ def wkv6(r, k, v, w, u, *, chunk: int = 64, interpret: bool | None = None):
 def swa(q, k, v, *, window: int, tile_q: int = 128, tile_k: int = 128,
         interpret: bool | None = None):
     """Sliding-window causal flash attention."""
-    interpret = _default_interpret() if interpret is None else interpret
+    interpret = resolve_interpret(interpret)
     return _swa_attention(q, k, v, window=window, tile_q=tile_q,
                           tile_k=tile_k, interpret=interpret)
 
@@ -101,7 +96,7 @@ def swa(q, k, v, *, window: int, tile_q: int = 128, tile_k: int = 128,
 def grouped_glu_ffn(x, wg, wu, wo, *, tile_c: int = 128, tile_d: int = 128,
                     tile_f: int = 128, interpret: bool | None = None):
     """Grouped per-expert GLU FFN (MoE hotspot)."""
-    interpret = _default_interpret() if interpret is None else interpret
+    interpret = resolve_interpret(interpret)
     return _grouped_glu_ffn(x, wg, wu, wo, tile_c=tile_c, tile_d=tile_d,
                             tile_f=tile_f, interpret=interpret)
 

@@ -51,6 +51,21 @@ def s2v_layer_sparse(theta4, x, neighbors, edge, base) -> jax.Array:
     return jax.nn.relu(base.astype(jnp.float32) + e3)
 
 
+def s2v_layer_csr(theta4, x, indices, row_ids, edge_w, base) -> jax.Array:
+    """One full CSR embedding layer: relu(base + θ4 @ nbr_sum) with
+    nbr_sum[b,k,n] = Σ_{e: row_ids[b,e]=n} x[b,k,indices[b,e]]·edge_w[b,e].
+    ``x`` is (B, K, N) WITHOUT a sentinel column — padded slots carry id N
+    and select the zero column appended here."""
+    n = x.shape[-1]
+    xp = jnp.pad(x.astype(jnp.float32), ((0, 0), (0, 0), (0, 1)))
+    gathered = jax.vmap(lambda xb, ib: xb[:, ib])(xp, indices)   # (B, K, E)
+    weighted = gathered * edge_w.astype(jnp.float32)[:, None, :]
+    nbr = jax.vmap(lambda wb, rb: jax.ops.segment_sum(
+        wb.T, rb, num_segments=n).T)(weighted, row_ids)
+    e3 = jnp.einsum("kj,bjn->bkn", theta4.astype(jnp.float32), nbr)
+    return jax.nn.relu(base.astype(jnp.float32) + e3)
+
+
 # ---------------------------------------------------------------------------
 # WKV6: RWKV-6 ("Finch") linear-attention recurrence with data-dependent
 # per-channel decay.  Shapes: r/k/w (BH, T, dk), v (BH, T, dv), u (BH, dk).

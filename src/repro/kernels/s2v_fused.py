@@ -10,11 +10,11 @@ Pallas kernel per layer instead of a chain of XLA ops:
   aggregation accumulating into a VMEM f32 scratch, with the θ4-matmul +
   residual + ReLU epilogue emitted by the final reduction step of each
   output tile.  The (B, K, N) neighbor-sum tensor never touches HBM.
-- ``fused_s2v_layer_sparse``: sparse rep — per node-tile on-chip one-hot
-  expansion of the (TN, D) neighbor list into a (TN, N) selection matrix
-  (see ``s2v_gather.py``), aggregation as x @ Mᵀ on the MXU, then the same
-  fused epilogue.  Sentinel-free: padded neighbor ids equal N, which
-  matches no one-hot column in [0, N), so x needs no sentinel column.
+- ``fused_s2v_layer_sparse``: sparse rep — the (TJ, TN) one-hot tile step
+  of ``s2v_gather.py`` (neighbor lists expanded on-chip into selection
+  blocks, aggregation as x @ M on the MXU), then the same fused epilogue.
+  Sentinel-free: padded neighbor ids equal N, which matches no one-hot
+  row in [0, N), so x needs no sentinel column.
 - ``mp_aggregate``:           aggregation-only partial kernel for the
   spatially-sharded dense path, where the cross-device psum (Alg. 2
   line 12) must run between aggregation and epilogue and therefore splits
@@ -26,8 +26,10 @@ adjacency/edge factors, θ4); every accumulation is f32 via
 Params remain f32 masters — casts happen at use (DESIGN.md §12).
 
 Tile sizes default to MXU-aligned (128) and are clamped for small problems.
-``interpret=None`` auto-detects the backend (compiled on TPU, interpret
-elsewhere; override with REPRO_PALLAS_INTERPRET — see ``backend.py``).
+``interpret=None`` compiles on TPU and interprets elsewhere
+(``backend.py``).  Every block is bounded by the tiles (and, for the
+sparse kernel, by the max degree D), so ``*_vmem_bytes`` never grows
+with N.
 """
 from __future__ import annotations
 
@@ -36,7 +38,9 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from .backend import resolve_interpret
+from .backend import (compiler_params, mxu_precision,
+                      pipelined_vmem_bytes, resolve_interpret)
+from .s2v_gather import sparse_layout, sparse_tile_step
 
 
 def _fused_dense_kernel(t4_ref, e_ref, a_ref, base_ref, o_ref, acc):
@@ -53,12 +57,14 @@ def _fused_dense_kernel(t4_ref, e_ref, a_ref, base_ref, o_ref, acc):
 
     acc[...] += jax.lax.dot_general(
         e_ref[0], a_ref[0], (((1,), (0,)), ((), ())),
+        precision=mxu_precision(e_ref.dtype),
         preferred_element_type=jnp.float32)
 
     @pl.when(l == pl.num_programs(2) - 1)
     def _epilogue():
         nbr = acc[...].astype(t4_ref.dtype)        # one rounding, f32 acc
         e3 = jax.lax.dot_general(t4_ref[...], nbr, (((1,), (0,)), ((), ())),
+                                 precision=mxu_precision(nbr.dtype),
                                  preferred_element_type=jnp.float32)
         o_ref[0] = jnp.maximum(base_ref[0] + e3, 0.0)
 
@@ -100,6 +106,8 @@ def fused_s2v_layer(theta4: jax.Array, embed: jax.Array, adj: jax.Array,
         out_specs=pl.BlockSpec((1, k, tn), lambda bi, ni, li: (bi, 0, ni)),
         out_shape=jax.ShapeDtypeStruct((b, k, npad), jnp.float32),
         scratch_shapes=[pltpu.VMEM((k, tn), jnp.float32)],
+        compiler_params=compiler_params("parallel", "parallel",
+                                        "arbitrary"),
         interpret=interpret,
     )(theta4.astype(cd), embed.astype(cd), adj.astype(cd),
       base.astype(jnp.float32))
@@ -116,6 +124,7 @@ def _agg_kernel(e_ref, a_ref, o_ref, acc):
 
     acc[...] += jax.lax.dot_general(
         e_ref[0], a_ref[0], (((1,), (0,)), ((), ())),
+        precision=mxu_precision(e_ref.dtype),
         preferred_element_type=jnp.float32)
 
     @pl.when(l == pl.num_programs(2) - 1)
@@ -154,40 +163,41 @@ def mp_aggregate(embed: jax.Array, adj: jax.Array, *, tile_n: int = 128,
         out_specs=pl.BlockSpec((1, k, tn), lambda bi, ni, li: (bi, 0, ni)),
         out_shape=jax.ShapeDtypeStruct((b, k, npad), jnp.float32),
         scratch_shapes=[pltpu.VMEM((k, tn), jnp.float32)],
+        compiler_params=compiler_params("parallel", "parallel",
+                                        "arbitrary"),
         interpret=interpret,
     )(embed.astype(cd), adj.astype(cd))
     return out[:, :, :n]
 
 
+def dense_vmem_bytes(k: int, *, epilogue: bool, tile_n: int = 128,
+                     tile_l: int = 128, compute_dtype=jnp.float32) -> int:
+    """Scoped VMEM of a dense kernel (fused layer or ``mp_aggregate``):
+    (K, TL) embedding, (TL, TN) adjacency and (K, TN) output blocks (+ θ4
+    and base with the epilogue) and the (K, TN) accumulator."""
+    f32 = jnp.float32
+    blocks = [((1, k, tile_l), compute_dtype),
+              ((1, tile_l, tile_n), compute_dtype), ((1, k, tile_n), f32)]
+    if epilogue:
+        blocks += [((k, k), compute_dtype), ((1, k, tile_n), f32)]
+    return pipelined_vmem_bytes(blocks, [((k, tile_n), f32)])
+
+
 def _fused_sparse_kernel(t4_ref, nbr_ref, edge_ref, x_ref, base_ref, o_ref,
-                         m_scratch):
-    """Grid (B, N/TN).  Blocks: nbr/edge (1, TN, D), x (1, K, N) [full,
-    sentinel-free], base (1, K, TN), out (1, K, TN); m_scratch (TN, N) VMEM.
+                         acc):
+    """Grid (B, Nl/TN, N/TJ), source-node axis j innermost (sequential).
 
-    Builds the tile's selection matrix M[i,j] = Σ_d edge[i,d]·[nbr[i,d]=j]
-    on-chip (padded ids equal N → match no column), aggregates as x @ Mᵀ on
-    the MXU, then applies the fused θ4 + residual + ReLU epilogue."""
-    nbr = nbr_ref[0]                                        # (TN, D) int32
-    w = edge_ref[0]                                         # (TN, D) cd
-    tn, dmax = nbr.shape
-    nf = m_scratch.shape[1]
-    cd = m_scratch.dtype
-    cols = jax.lax.broadcasted_iota(jnp.int32, (tn, nf), 1)
+    The shared one-hot tile step accumulates the (K, TN) neighbor sum; the
+    last j step applies the fused θ4 + residual + ReLU epilogue."""
+    sparse_tile_step(nbr_ref, edge_ref, x_ref, acc)
 
-    def body(d, m):
-        onehot = (cols == nbr[:, d][:, None]).astype(cd)
-        return m + w[:, d][:, None] * onehot
-
-    m_scratch[...] = jax.lax.fori_loop(
-        0, dmax, body, jnp.zeros((tn, nf), cd))
-    # nbrsum[k, i] = Σ_j x[k, j] · M[i, j] — MXU contraction over j
-    nbrsum = jax.lax.dot_general(
-        x_ref[0], m_scratch[...], (((1,), (1,)), ((), ())),
-        preferred_element_type=jnp.float32)                  # (K, TN) f32
-    e3 = jax.lax.dot_general(
-        t4_ref[...], nbrsum.astype(cd), (((1,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32)
-    o_ref[0] = jnp.maximum(base_ref[0] + e3, 0.0)
+    @pl.when(pl.program_id(2) == pl.num_programs(2) - 1)
+    def _epilogue():
+        e3 = jax.lax.dot_general(
+            t4_ref[...], acc[...].astype(t4_ref.dtype),
+            (((1,), (0,)), ((), ())), precision=mxu_precision(t4_ref.dtype),
+            preferred_element_type=jnp.float32)
+        o_ref[0] = jnp.maximum(base_ref[0] + e3, 0.0)
 
 
 def fused_s2v_layer_sparse(theta4: jax.Array, x: jax.Array,
@@ -199,7 +209,7 @@ def fused_s2v_layer_sparse(theta4: jax.Array, x: jax.Array,
     ``ref.s2v_layer_sparse``.
 
     x:         (B, K, N) float — embeddings, NO sentinel column (padded
-               neighbor ids equal N and match no one-hot column).
+               neighbor ids equal N and match no one-hot row).
     neighbors: (B, Nl, D) int32 — padded neighbor ids (sentinel N).
     edge:      (B, Nl, D) float — residual-edge factors (0 for padding).
     base:      (B, K, Nl) float — embed1 + embed2 residual term.
@@ -207,33 +217,30 @@ def fused_s2v_layer_sparse(theta4: jax.Array, x: jax.Array,
     """
     interpret = resolve_interpret(interpret)
     cd = jnp.dtype(compute_dtype)
-    b, k, n = x.shape
+    b, k, _ = x.shape
     _, nl, d = neighbors.shape
-    tn = min(tile_n, nl)
-    pad = (-nl) % tn
-    if pad:
-        # padding nodes point at the sentinel id N with zero edge weight and
-        # zero base → their fused output is relu(0) = 0, sliced off below
-        neighbors = jnp.pad(neighbors, ((0, 0), (0, pad), (0, 0)),
-                            constant_values=n)
-        edge = jnp.pad(edge, ((0, 0), (0, pad), (0, 0)))
-        base = jnp.pad(base, ((0, 0), (0, 0), (0, pad)))
-    nlpad = nl + pad
+    x, nbr_t, edge_t, tn, tj = sparse_layout(
+        x.astype(cd), neighbors, edge, tile_n=tile_n)
+    nlpad, nxpad = nbr_t.shape[2], x.shape[2]
+    # padding nodes have zero edge weight and zero base → relu(0) = 0
+    base = jnp.pad(base.astype(jnp.float32),
+                   ((0, 0), (0, 0), (0, nlpad - nl)))
 
     out = pl.pallas_call(
         _fused_sparse_kernel,
-        grid=(b, nlpad // tn),
+        grid=(b, nlpad // tn, nxpad // tj),
         in_specs=[
-            pl.BlockSpec((k, k), lambda bi, ni: (0, 0)),
-            pl.BlockSpec((1, tn, d), lambda bi, ni: (bi, ni, 0)),
-            pl.BlockSpec((1, tn, d), lambda bi, ni: (bi, ni, 0)),
-            pl.BlockSpec((1, k, n), lambda bi, ni: (bi, 0, 0)),
-            pl.BlockSpec((1, k, tn), lambda bi, ni: (bi, 0, ni)),
+            pl.BlockSpec((k, k), lambda bi, ni, ji: (0, 0)),
+            pl.BlockSpec((1, d, tn), lambda bi, ni, ji: (bi, 0, ni)),
+            pl.BlockSpec((1, d, tn), lambda bi, ni, ji: (bi, 0, ni)),
+            pl.BlockSpec((1, k, tj), lambda bi, ni, ji: (bi, 0, ji)),
+            pl.BlockSpec((1, k, tn), lambda bi, ni, ji: (bi, 0, ni)),
         ],
-        out_specs=pl.BlockSpec((1, k, tn), lambda bi, ni: (bi, 0, ni)),
+        out_specs=pl.BlockSpec((1, k, tn), lambda bi, ni, ji: (bi, 0, ni)),
         out_shape=jax.ShapeDtypeStruct((b, k, nlpad), jnp.float32),
-        scratch_shapes=[pltpu.VMEM((tn, n), cd)],
+        scratch_shapes=[pltpu.VMEM((k, tn), jnp.float32)],
+        compiler_params=compiler_params("parallel", "parallel",
+                                        "arbitrary"),
         interpret=interpret,
-    )(theta4.astype(cd), neighbors.astype(jnp.int32), edge.astype(cd),
-      x.astype(cd), base.astype(jnp.float32))
+    )(theta4.astype(cd), nbr_t, edge_t, x, base)
     return out[:, :, :nl]

@@ -78,19 +78,15 @@ def main():
                     help="AOT-compile every (bucket, problem) executable "
                          "before the first request (zero cold compiles on "
                          "the request path)")
-    ap.add_argument("--compile-cache", default=None,
-                    help="directory for jax's persistent executable cache "
-                         "(warm restarts skip even the warmup compiles)")
     args = ap.parse_args()
 
     import jax
     from ..core import PolicyConfig, init_policy, parse_spatial
     from ..core.graphs import erdos_renyi, barabasi_albert, social_like
-    from ..serving import (GraphSolverService, enable_compile_cache,
-                           make_workload, run_open_loop)
+    from ..compile_cache import setup_compile_cache
+    from ..serving import GraphSolverService, make_workload, run_open_loop
 
-    if args.compile_cache:
-        enable_compile_cache(args.compile_cache)
+    setup_compile_cache()
 
     cfg = PolicyConfig(embed_dim=args.embed_dim, num_layers=2,
                        graph_rep=args.rep,

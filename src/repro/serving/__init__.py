@@ -8,5 +8,4 @@ from .bucketing import (MIN_BUCKET, BatchPlan, bucket_nodes, build_plan,
 from .loadgen import LoadReport, Workload, make_workload, run_open_loop
 from .scheduler import DeadlineScheduler, PendingRequest
 from .service import (GraphSolverService, ServiceOverloaded, ServiceStats,
-                      SolveFuture, SolveRequest, SolveResponse,
-                      enable_compile_cache)
+                      SolveFuture, SolveRequest, SolveResponse)

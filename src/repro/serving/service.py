@@ -28,7 +28,8 @@ Two serving modes share every layer below submission:
 (bucket, problem, mesh) executable OFF the request path, so the first real
 dispatch of a bucket never eats a cold jit compile; compile time is
 accounted in ``ServiceStats.compile_seconds``, never in
-``solve_seconds``.  Pair with :func:`enable_compile_cache` to persist
+``solve_seconds``.  Pair with
+:func:`repro.compile_cache.enable_compile_cache` to persist
 compiled executables across process restarts.
 
     svc = GraphSolverService.from_checkpoint(ckpt_dir, cfg)
@@ -147,29 +148,6 @@ class SolveFuture:
     def _set_exception(self, exc: BaseException) -> None:
         self._exception = exc
         self._event.set()
-
-
-def enable_compile_cache(cache_dir: str) -> bool:
-    """Best-effort jax persistent compilation cache: compiled executables
-    are serialized under ``cache_dir``, so a RESTARTED server's
-    ``warmup()`` deserializes instead of recompiling — the
-    zero-cold-compile restart path (DESIGN.md §14).  Returns False when
-    this jax build has no compilation cache (the in-process ``warmup()``
-    contract is unaffected either way)."""
-    import jax
-    try:
-        jax.config.update("jax_compilation_cache_dir", str(cache_dir))
-        # default thresholds skip small/fast-compiling executables; the
-        # service wants EVERY bucket executable persisted
-        jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
-        try:
-            jax.config.update("jax_persistent_cache_min_entry_size_bytes",
-                              -1)
-        except AttributeError:
-            pass
-        return True
-    except AttributeError:
-        return False
 
 
 class GraphSolverService:
@@ -414,7 +392,8 @@ class GraphSolverService:
         After a warmup covering the traffic's buckets,
         ``stats.compiles == 0`` holds through the measured window — the
         acceptance contract guarded by `benchmarks/serving_latency.py`.
-        Combined with :func:`enable_compile_cache`, a restarted process
+        Combined with :func:`repro.compile_cache.enable_compile_cache`, a
+        restarted process
         warms from the on-disk executable cache instead of recompiling."""
         t0 = time.perf_counter()
         compiled = []

@@ -1,9 +1,9 @@
-"""Compatibility shims across JAX API generations.
+"""shard_map and mesh-axis spellings used across the repo.
 
-The repo targets current JAX (``jax.shard_map``, ``check_vma``,
-``jax.sharding.AxisType``) but must also run on older runtimes where
-shard_map still lives in ``jax.experimental`` (``check_rep``) and meshes
-have no axis_types.  Everything version-dependent funnels through here.
+``shard_map_nocheck`` is ``jax.shard_map`` with VMA checking off (the
+collectives' outputs are value-identical across a graph group, which the
+checker cannot prove statically); ``auto_axis_types_kw`` gives a mesh
+Auto axis types.
 """
 from __future__ import annotations
 
@@ -11,26 +11,15 @@ import functools
 
 import jax
 
-if hasattr(jax, "shard_map"):
-    _shard_map = jax.shard_map
-    _NOCHECK = {"check_vma": False}
-else:                                                # pragma: no cover
-    from jax.experimental.shard_map import shard_map as _shard_map
-    _NOCHECK = {"check_rep": False}
-
-HAS_AXIS_TYPES = hasattr(jax.sharding, "AxisType")
-
 
 def shard_map_nocheck(fn=None, **kw):
-    """``jax.shard_map`` with replication/VMA checking disabled, spelled
-    correctly for the running JAX version.  Usable as decorator or call."""
+    """``jax.shard_map`` with ``check_vma=False``.  Usable as decorator or
+    call."""
     if fn is None:
         return functools.partial(shard_map_nocheck, **kw)
-    return _shard_map(fn, **kw, **_NOCHECK)
+    return jax.shard_map(fn, **kw, check_vma=False)
 
 
 def auto_axis_types_kw(n_axes: int) -> dict:
-    """``axis_types=(Auto,)*n`` kwargs when the runtime supports them."""
-    if HAS_AXIS_TYPES:
-        return {"axis_types": (jax.sharding.AxisType.Auto,) * n_axes}
-    return {}
+    """``axis_types=(Auto,)*n`` kwargs for ``jax.make_mesh``."""
+    return {"axis_types": (jax.sharding.AxisType.Auto,) * n_axes}

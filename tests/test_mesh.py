@@ -53,8 +53,7 @@ def test_minibatch_divisibility_checked():
 
 
 def _train_params(rep_name, spec, *, n=16, steps=6, tau=2,
-                  collectives="auto", stage_boundary=None,
-                  target_mode="stored"):
+                  collectives="auto", target_mode="stored"):
     """Params after `steps` fused train steps (eps=0) on the given mesh
     spec — the DESIGN.md §8 RNG schedule makes this deterministic, so mesh
     shapes AND collective strategies are directly comparable."""
@@ -66,8 +65,7 @@ def _train_params(rep_name, spec, *, n=16, steps=6, tau=2,
                        eps_start=0.0, eps_end=0.0, graph_rep=rep_name,
                        spatial=spec, collectives=collectives)
     agent = Agent(cfg, num_nodes=n)
-    fused = get_train_step(cfg, rep=rep, tau=tau, target_mode=target_mode,
-                           stage_boundary=stage_boundary)
+    fused = get_train_step(cfg, rep=rep, tau=tau, target_mode=target_mode)
     mesh = mesh_from_spec(spec)
     es = engine_init(cfg, agent.params, agent.opt, n, seed=0, mesh=mesh)
     source = rep.prepare_dataset(adj)
@@ -103,6 +101,21 @@ def test_train_step_parity_across_mesh_shapes(rep_name):
 
 @multidevice
 @needs4
+def test_csr_data_parallel_train_parity():
+    """csr trains data-parallel only; its per-graph shard_map scoring over
+    `data` (acting, targets, GD loss) keeps (2,1) at the one-device
+    params."""
+    base, base_losses = _train_params("csr", 0)
+    params, losses = _train_params("csr", (2, 1))
+    for a, b in zip(jax.tree.leaves(base), jax.tree.leaves(params)):
+        np.testing.assert_allclose(b, a, atol=1e-6)
+    warm = np.isfinite(base_losses)
+    np.testing.assert_allclose(np.asarray(losses)[warm],
+                               np.asarray(base_losses)[warm], atol=1e-6)
+
+
+@multidevice
+@needs4
 @pytest.mark.parametrize("rep_name", ["dense", "sparse"])
 def test_fused_solve_parity_across_mesh_shapes(rep_name):
     """One full adaptive solve is bit-identical (solutions, eval counts,
@@ -124,13 +137,9 @@ def test_fused_solve_parity_across_mesh_shapes(rep_name):
 @pytest.mark.parametrize("rep_name", ["dense", "sparse"])
 def test_manual_vs_gspmd_vs_single_device_parity(rep_name):
     """Three-way parity (DESIGN.md §10): the manual-collective path, the
-    staged-gspmd reference and the single-device step agree to ≤1e-6 on
-    loss and params at (2,1), (1,2) and (2,2), fused train step + a full
-    solve with the trained params.  (Fresh-target mode at (2,2) is the
-    manual path's exclusive: the gspmd reference inherits the upstream
-    mispartitioning through the unstaged TD-target remat there, so only
-    manual is compared against the reference — the measured seed delta is
-    2.5e-3.)"""
+    gspmd reference and the single-device step agree to ≤1e-6 on loss and
+    params at (2,1), (1,2) and (2,2), in both target modes, fused train
+    step + a full solve with the trained params."""
     adj = random_graph_batch("er", 16, 4, seed=0, rho=0.3)
     for target_mode in ("stored", "fresh"):
         base, base_losses = _train_params(rep_name, 0,
@@ -139,9 +148,6 @@ def test_manual_vs_gspmd_vs_single_device_parity(rep_name):
                     engine="host")
         for spec in [(2, 1), (1, 2), (2, 2)]:
             for coll in ("manual", "gspmd"):
-                if coll == "gspmd" and target_mode == "fresh" \
-                        and spec == (2, 2):
-                    continue          # known-broken upstream; see docstring
                 params, losses = _train_params(rep_name, spec,
                                                collectives=coll,
                                                target_mode=target_mode)
@@ -283,35 +289,17 @@ def test_new_env_solve_parity_across_mesh_shapes(problem, rep_name):
 @multidevice
 @needs4
 def test_gspmd_mispartitioning_canary():
-    """Canary for the upstream jax GSPMD bug behind the DESIGN.md §10
-    staging workaround: with boundary staging DISABLED, the (2,2) fused
-    train step must still diverge from the single-device reference on the
-    jax versions this repo pins.
-
-    If this test ever fails because the unstaged run MATCHES the
-    reference, the upstream mispartitioning is fixed on the installed jax
-    — retire the workaround: drop the "live" staging scope default in
-    `spatial.spatial_train_minibatch_fn`, delete this canary, and
-    consider demoting the manual-collective path from default to opt-in
-    (its operand-memory win stands regardless).  (The staged path's own
-    correctness — staged (2,2) == (1,1) — is enforced by
-    test_manual_vs_gspmd_vs_single_device_parity above.)
-
-    The unstaged run is selected through the explicit `stage_boundary`
-    plumbing of `engine.get_train_step` (a distinct lru_cache key — no
-    module-global override, no cache clearing).
-    """
-    base, _ = _train_params("dense", 0)
-    unstaged, _ = _train_params("dense", (2, 2), collectives="gspmd",
-                                stage_boundary="none")
-    dmax = max(float(np.abs(a - b).max())
-               for a, b in zip(jax.tree.leaves(base),
-                               jax.tree.leaves(unstaged)))
-    assert dmax > 1e-6, (
-        f"unstaged (2,2) fused train step now matches the single-device "
-        f"reference (max param delta {dmax:.2e}) — the upstream GSPMD "
-        f"mispartitioning appears FIXED on this jax version; retire the "
-        f"boundary-staging workaround (DESIGN.md §10)")
+    """The gspmd reference path on the full 2-D mesh, with no operand
+    staged at the shard_map boundary, trains to the single-device params
+    (≤1e-6, dense, both target modes).  Older JAX mispartitioned the
+    in-jit gathered minibatch here (order-1e-3 errors); this guards the
+    retirement of that workaround."""
+    for target_mode in ("stored", "fresh"):
+        base, _ = _train_params("dense", 0, target_mode=target_mode)
+        gspmd, _ = _train_params("dense", (2, 2), collectives="gspmd",
+                                 target_mode=target_mode)
+        for a, b in zip(jax.tree.leaves(base), jax.tree.leaves(gspmd)):
+            np.testing.assert_allclose(b, a, atol=1e-6, err_msg=target_mode)
 
 
 @multidevice

@@ -10,12 +10,12 @@ import numpy as np
 import jax
 import pytest
 
+from repro.compile_cache import enable_compile_cache
 from repro.core import PolicyConfig, init_policy
 from repro.core.graphs import erdos_renyi
 from repro.serving import (DeadlineScheduler, GraphSolverService,
                            PendingRequest, ServiceOverloaded,
-                           enable_compile_cache, make_workload,
-                           run_open_loop)
+                           make_workload, run_open_loop)
 
 
 @pytest.fixture(scope="module")

@@ -1,0 +1,153 @@
+"""Compile rehearsal on a described TPU v5e chip (no chip attached).
+
+Every main-path S2V kernel is compiled with ``interpret=False`` at K=32
+on the train shapes, on the paper's largest dense graph (W1, N=21,000)
+and on the BA N=16,384 solve shapes, and must lower to a Mosaic kernel
+(``tpu_custom_call``).  The size rule ``s2v_kernel_fits`` is held to the
+compiler: at the largest shape it admits the kernel compiles, and just
+past its bound the compiler refuses the kernel for VMEM.
+
+The topology is described only inside the module fixture, never while
+this file is imported, so pytest-xdist workers all collect the same
+tests and only the worker given this file loads the TPU compiler.
+"""
+import jax
+import jax.numpy as jnp
+import pytest
+
+from repro.core.s2v import s2v_kernel_fits
+from repro.kernels import s2v_csr, s2v_fused, s2v_gather
+
+K = 32
+# (B, N, D, E) per shape; D and E are the BA d=4 / d=10 maxima from
+# `graphs.barabasi_albert_edges` with seed 0.
+SHAPES = {
+    "train": dict(b=64, n=1024, d=174, e=8032),
+    "w1": dict(b=1, n=21_000),
+    "w1_sp4": dict(b=1, nl=5_250, n=21_000),
+    "ba16k": dict(b=1, n=16_384, d=1_089, e=326_208),
+}
+SHAPED = [("dense_fused", "train"), ("mp_aggregate", "train"),
+          ("sparse_fused", "train"), ("gather", "train"),
+          ("csr_fused", "train"), ("dense_fused", "w1"),
+          ("mp_aggregate", "w1_sp4"), ("sparse_fused", "ba16k"),
+          ("gather", "ba16k"), ("csr_fused", "ba16k")]
+# every kernel in f32; bf16 where the kernel takes a compute dtype (the
+# gather kernel is f32-only)
+CASES = [(k, s, c) for k, s in SHAPED for c in ("f32", "bf16")
+         if not (k == "gather" and c == "bf16")]
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    """One device of a described v5e:2x2 host, with the persistent
+    compilation cache off (entries written here cannot be read back)."""
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+    from jax.sharding import SingleDeviceSharding
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setenv("TPU_LOG_DIR", "disabled")
+        try:
+            topo = topologies.get_topology_desc(platform="tpu",
+                                                topology_name="v5e:2x2")
+        except Exception as e:          # no TPU compiler for this jax
+            pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+        was = jax.config.jax_enable_compilation_cache
+        jax.config.update("jax_enable_compilation_cache", False)
+        compilation_cache.reset_cache()
+        try:
+            yield SingleDeviceSharding(topo.devices[0])
+        finally:
+            jax.config.update("jax_enable_compilation_cache", was)
+
+
+def _spec(sharding, shape, dtype=jnp.float32):
+    return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
+
+
+def _lower(sharding, kernel, cd=jnp.float32, *, b, n, d=0, e=0, nl=None):
+    """Lower one kernel at the given shapes for the described chip."""
+    s = lambda *shape, dt=jnp.float32: _spec(sharding, shape, dt)
+    i32 = jnp.int32
+    nl = n if nl is None else nl
+    if kernel == "dense_fused":
+        fn = lambda t, x, a, bb: s2v_fused.fused_s2v_layer(
+            t, x, a, bb, compute_dtype=cd, interpret=False)
+        args = (s(K, K), s(b, K, n), s(b, n, n), s(b, K, n))
+    elif kernel == "mp_aggregate":
+        fn = lambda x, a: s2v_fused.mp_aggregate(
+            x, a, compute_dtype=cd, interpret=False)
+        args = (s(b, K, nl), s(b, nl, n))
+    elif kernel == "sparse_fused":
+        fn = lambda t, x, nb, ed, bb: s2v_fused.fused_s2v_layer_sparse(
+            t, x, nb, ed, bb, compute_dtype=cd, interpret=False)
+        args = (s(K, K), s(b, K, n), s(b, n, d, dt=i32), s(b, n, d),
+                s(b, K, n))
+    elif kernel == "gather":
+        fn = lambda x, nb, ed: s2v_gather.sparse_mp_aggregate(
+            x, nb, ed, interpret=False)
+        args = (s(b, K, n + 1), s(b, n, d, dt=i32), s(b, n, d))
+    else:
+        fn = lambda t, x, ix, r, w, bb: s2v_csr.fused_s2v_layer_csr(
+            t, x, ix, r, w, bb, compute_dtype=cd, interpret=False)
+        args = (s(K, K), s(b, K, n), s(b, e, dt=i32), s(b, e, dt=i32),
+                s(b, e), s(b, K, n))
+    return jax.jit(fn).lower(*args)
+
+
+@pytest.mark.parametrize("kernel,shape,compute", CASES)
+def test_kernel_compiles_for_v5e(one_chip, kernel, shape, compute):
+    cd = {"f32": jnp.float32, "bf16": jnp.bfloat16}[compute]
+    compiled = _lower(one_chip, kernel, cd, **SHAPES[shape]).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+def _largest(fits, start, step):
+    """Largest ``start + i·step`` that ``fits``."""
+    assert fits(start)
+    x = start
+    while fits(x + step):
+        x += step
+    return x
+
+
+# (kernel, rep, compute, which shape the bound is on)
+BOUNDS = [("csr_fused", "csr", "f32", "n"),
+          ("csr_fused", "csr", "bf16", "n"),
+          ("sparse_fused", "sparse", "f32", "d"),
+          ("gather", "sparse", "f32", "d")]
+
+
+@pytest.mark.parametrize("kernel,rep,compute,axis", BOUNDS)
+def test_size_rule_matches_compiler(one_chip, kernel, rep, compute, axis):
+    """The rule admits the kernel up to its VMEM bound and the compiler
+    accepts it there; just past it (by about half a MiB, twice the rule's
+    reserve) the rule picks XLA and the compiler refuses the kernel for
+    VMEM."""
+    cd = {"f32": jnp.float32, "bf16": jnp.bfloat16}[compute]
+    agg = kernel == "gather"
+    if axis == "n":                       # CSR: whole (K, N) panels
+        fits = lambda n: s2v_kernel_fits(rep, k=K, n=n, compute_dtype=cd)
+        inside = _largest(fits, 256, 256)
+        outside = inside + 2048
+        shape = lambda v: dict(b=1, n=v, e=326_208)
+    else:                                 # sparse: (D, TN) edge-list blocks
+        fits = lambda d: s2v_kernel_fits(rep, k=K, max_degree=d,
+                                         compute_dtype=cd,
+                                         aggregate_only=agg)
+        inside = _largest(fits, 8, 8)
+        outside = inside + 256
+        # N large enough that XLA cannot hold the (B, D, N) lists in VMEM
+        shape = lambda v: dict(b=1, n=1024, d=v)
+    assert not fits(outside)
+    compiled = _lower(one_chip, kernel, cd, **shape(inside)).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    with pytest.raises(Exception, match="vmem"):
+        _lower(one_chip, kernel, cd, **shape(outside)).compile()
+
+
+def test_dense_kernels_fit_at_every_n():
+    """The dense kernels are tiled over both node axes: their VMEM need is
+    independent of N, so the rule admits them at any graph size."""
+    for agg in (False, True):
+        assert s2v_kernel_fits("dense", k=K, aggregate_only=agg)
