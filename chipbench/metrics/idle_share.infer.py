@@ -1,0 +1,9 @@
+"""idle_share.infer: share of the traced window in which no operation ran
+on the device, in the solve cells (moves infer_step_ms)."""
+
+
+def read(ctx):
+    t = ctx.trace
+    if t is None or t.window_s <= 0 or t.busy_s <= 0:
+        return None
+    return 100.0 * (1.0 - t.busy_s / t.window_s)
