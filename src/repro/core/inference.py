@@ -91,13 +91,17 @@ def apply_selection(state, scores, candidate, use_adaptive: bool,
     the host-loop step and the fused while_loop body so the two engines
     stay bit-identical per problem.  Note the MIS prune scan is capped at
     ``env._MAX_COMMIT`` kept picks per evaluation regardless of ``max_d``
-    (independence filtering is inherently sequential)."""
-    sel, ncommit = select_top_d(scores, candidate, use_adaptive, max_d)
-    prune = env_lib.prune_rule(problem)
-    if prune is not None:
-        sel = prune(state, sel, scores)
-        ncommit = sel.sum(-1).astype(jnp.int32)
-    new_state, done = env_lib.commit_rule(problem)(state, sel)
+    (independence filtering is inherently sequential).  Selection and
+    prune run under the named scope ``env.select``, the commit under
+    ``env.commit``."""
+    with jax.named_scope("env.select"):
+        sel, ncommit = select_top_d(scores, candidate, use_adaptive, max_d)
+        prune = env_lib.prune_rule(problem)
+        if prune is not None:
+            sel = prune(state, sel, scores)
+            ncommit = sel.sum(-1).astype(jnp.int32)
+    with jax.named_scope("env.commit"):
+        new_state, done = env_lib.commit_rule(problem)(state, sel)
     return new_state, done, ncommit
 
 
@@ -179,44 +183,14 @@ def solve(params: PolicyParams, adj0, *, num_layers: int = 2,
     if engine not in ("host", "device"):
         raise ValueError(f"unknown inference engine {engine!r}")
     rep = get_rep(rep)
-    state = init_solve_state(rep, adj0, problem)
-    n = state.num_nodes
-    max_evals = max_evals or (n + max_d)
-    dp, _sp = normalize_spatial(spatial)
-
     if engine == "device" and step_fn is None:
-        if state.batch % dp:
-            raise ValueError(f"batch {state.batch} not divisible by the "
-                             f"data-axis size {dp} of mesh spec {spatial!r}")
-        from .engine import get_solve_step
-        # Donating the solve state is only safe when it does not alias the
-        # caller's arrays: a prebuilt sparse/csr batch (or state) shares
-        # its topology buffers with the state init_solve_state returns,
-        # and donation would delete them out from under the caller.
-        owned = {id(getattr(adj0, f)) for f in
-                 ("indptr", "indices", "edge_mask", "neighbors", "valid",
-                  "solution", "candidate") if getattr(adj0, f, None)
-                 is not None} | {id(adj0)}
-        shares = any(id(x) in owned for x in jax.tree.leaves(state))
-        fused = get_solve_step(rep=rep, problem=problem,
-                               num_layers=num_layers,
-                               use_adaptive=multi_node, spatial=spatial,
-                               kernel=kernel, compute=compute, max_d=max_d,
-                               donate=not shares)
-        if (dp, _sp) != (1, 1):
-            # batch-sharded placement up front: the while_loop's resident
-            # layout, so the donated state buffers alias from call one
-            from .mesh import make_mesh, shard_batch
-            state = shard_batch(make_mesh(dp, _sp), state)
-        # the solve's single host↔device round-trip: one result fetch
-        out, evals, committed = fused(params, state,
-                                      jnp.asarray(max_evals, jnp.int32))
-        sol, evals, committed = jax.device_get(
-            (out.solution, evals, committed))
-        return InferenceResult(solution=sol,
-                               sizes=sol.sum(-1).astype(np.int64),
-                               policy_evals=int(evals),
-                               nodes_committed=committed.astype(np.int64))
+        return _solve_fused(params, adj0, rep=rep, problem=problem,
+                            num_layers=num_layers, multi_node=multi_node,
+                            max_evals=max_evals, spatial=spatial,
+                            kernel=kernel, compute=compute, max_d=max_d)
+    state = init_solve_state(rep, adj0, problem)
+    max_evals = max_evals or (state.num_nodes + max_d)
+    dp, _sp = normalize_spatial(spatial)
     if (dp, _sp) != (1, 1):
         raise ValueError("spatial solve runs on the fused path only; it is "
                          "incompatible with engine='host' and with step_fn "
@@ -237,6 +211,54 @@ def solve(params: PolicyParams, adj0, *, num_layers: int = 2,
     sol = np.asarray(state.solution)
     return InferenceResult(solution=sol, sizes=sol.sum(-1).astype(np.int64),
                            policy_evals=evals, nodes_committed=committed)
+
+
+def _solve_fused(params: PolicyParams, adj0, *, rep: GraphRep, problem: str,
+                 num_layers: int, multi_node: bool,
+                 max_evals: Optional[int], spatial, kernel: str,
+                 compute: str, max_d: int) -> InferenceResult:
+    """``solve`` on the device engine: one fused ``while_loop``
+    (``engine.get_solve_step``) and one fetch of the answer, under three
+    profiler spans on the host: ``solve.prepare`` (state init, step lookup,
+    placement), ``solve.dispatch`` (the call into the fused program) and
+    ``solve.fetch`` (the ``device_get`` of the answer)."""
+    from .engine import get_solve_step
+    from .mesh import make_mesh, normalize_spatial, shard_batch
+    with jax.profiler.TraceAnnotation("solve.prepare"):
+        state = init_solve_state(rep, adj0, problem)
+        max_evals = max_evals or (state.num_nodes + max_d)
+        dp, sp = normalize_spatial(spatial)
+        if state.batch % dp:
+            raise ValueError(f"batch {state.batch} not divisible by the "
+                             f"data-axis size {dp} of mesh spec {spatial!r}")
+        # Donating the solve state is only safe when it does not alias the
+        # caller's arrays: a prebuilt sparse/csr batch (or state) shares
+        # its topology buffers with the state init_solve_state returns,
+        # and donation would delete them out from under the caller.
+        owned = {id(getattr(adj0, f)) for f in
+                 ("indptr", "indices", "edge_mask", "neighbors", "valid",
+                  "solution", "candidate") if getattr(adj0, f, None)
+                 is not None} | {id(adj0)}
+        shares = any(id(x) in owned for x in jax.tree.leaves(state))
+        fused = get_solve_step(rep=rep, problem=problem,
+                               num_layers=num_layers,
+                               use_adaptive=multi_node, spatial=spatial,
+                               kernel=kernel, compute=compute, max_d=max_d,
+                               donate=not shares)
+        if (dp, sp) != (1, 1):
+            # batch-sharded placement up front: the while_loop's resident
+            # layout, so the donated state buffers alias from call one
+            state = shard_batch(make_mesh(dp, sp), state)
+    with jax.profiler.TraceAnnotation("solve.dispatch"):
+        out, evals, committed = fused(params, state,
+                                      jnp.asarray(max_evals, jnp.int32))
+    # the solve's single host↔device round-trip: one result fetch
+    with jax.profiler.TraceAnnotation("solve.fetch"):
+        sol, evals, committed = jax.device_get(
+            (out.solution, evals, committed))
+    return InferenceResult(solution=sol, sizes=sol.sum(-1).astype(np.int64),
+                           policy_evals=int(evals),
+                           nodes_committed=committed.astype(np.int64))
 
 
 def best_trajectory_cut(params: PolicyParams, adj0, *, num_layers: int = 2,

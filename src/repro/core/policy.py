@@ -92,8 +92,15 @@ def policy_scores(
     kernel: str = "fused",
     compute: str = "f32",
 ) -> jax.Array:
-    """Q(EM(Aᶦ, Sᶦ), Cᶦ): (B, Nl) masked scores of local candidates."""
-    emb = embed_local(params.em, adj_local, sol_local,
-                      num_layers=num_layers, axis=axis, kernel=kernel,
-                      compute=compute)
-    return scores_local(params.q, emb, cand_local, axis=axis, masked=masked)
+    """Q(EM(Aᶦ, Sᶦ), Cᶦ): (B, Nl) masked scores of local candidates.
+
+    The embedding runs under the named scope ``s2v.embed`` and the Q head
+    under ``q.head`` (as in every score path), so compiled operations and
+    their device-trace events carry the layer in their ``op_name``."""
+    with jax.named_scope("s2v.embed"):
+        emb = embed_local(params.em, adj_local, sol_local,
+                          num_layers=num_layers, axis=axis, kernel=kernel,
+                          compute=compute)
+    with jax.named_scope("q.head"):
+        return scores_local(params.q, emb, cand_local, axis=axis,
+                            masked=masked)

@@ -204,9 +204,11 @@ def csr_policy_scores(params: PolicyParams, g, sol: jax.Array,
                       masked: bool = True, residual=True,
                       kernel: str = "fused",
                       compute: str = "f32") -> jax.Array:
-    emb = embed_csr(params.em, g, sol, num_layers=num_layers,
-                    residual=residual, kernel=kernel, compute=compute)
-    return scores_local(params.q, emb, cand, masked=masked)
+    with jax.named_scope("s2v.embed"):
+        emb = embed_csr(params.em, g, sol, num_layers=num_layers,
+                        residual=residual, kernel=kernel, compute=compute)
+    with jax.named_scope("q.head"):
+        return scores_local(params.q, emb, cand, masked=masked)
 
 
 def csr_state_bytes(g) -> int:

@@ -54,7 +54,8 @@ from .s2v import (check_kernel, compute_dtype, f32_matmuls,
 __all__ = ["SparseGraphBatch", "sparse_batch_from_dense", "embed_sparse",
            "embed_sparse_local", "residual_edge_factors",
            "closed_edge_factors", "closed_keep_local", "edge_factors",
-           "sparse_policy_scores", "sparse_state_bytes"]
+           "sparse_local_scores", "sparse_policy_scores",
+           "sparse_state_bytes"]
 
 
 def residual_edge_factors(nbr_local: jax.Array, valid_local: jax.Array,
@@ -292,15 +293,37 @@ def embed_sparse(params, g, sol: jax.Array, *, num_layers: int,
                               gather_impl=gather_impl)
 
 
+def sparse_local_scores(params: PolicyParams, nbr_local: jax.Array,
+                        valid_local: jax.Array, sol_local: jax.Array,
+                        cand_local: jax.Array, *, num_layers: int,
+                        residual=True, axis: Optional[str] = None,
+                        masked: bool = True, kernel: str = "fused",
+                        compute: str = "f32",
+                        gather_impl: Optional[Callable] = None) -> jax.Array:
+    """Q(EM(topology, S), C): (B, Nl) scores of the resident nodes, the
+    edge factors and embedding under the named scope ``s2v.embed`` and the
+    Q head under ``q.head``.  ``axis`` as in :func:`embed_sparse_local`."""
+    with jax.named_scope("s2v.embed"):
+        edge = edge_factors(nbr_local, valid_local, sol_local, residual,
+                            axis=axis)
+        emb = embed_sparse_local(params.em, nbr_local, edge, sol_local,
+                                 num_layers=num_layers, axis=axis,
+                                 kernel=kernel, compute=compute,
+                                 gather_impl=gather_impl)
+    with jax.named_scope("q.head"):
+        return scores_local(params.q, emb, cand_local, axis=axis,
+                            masked=masked)
+
+
 def sparse_policy_scores(params: PolicyParams, g, sol: jax.Array,
                          cand: jax.Array, *, num_layers: int,
                          masked: bool = True, residual=True,
                          kernel: str = "fused", compute: str = "f32",
                          gather_impl: Optional[Callable] = None) -> jax.Array:
-    emb = embed_sparse(params.em, g, sol, num_layers=num_layers,
-                       residual=residual, kernel=kernel, compute=compute,
-                       gather_impl=gather_impl)
-    return scores_local(params.q, emb, cand, masked=masked)
+    return sparse_local_scores(params, g.neighbors, g.valid, sol, cand,
+                               num_layers=num_layers, residual=residual,
+                               masked=masked, kernel=kernel, compute=compute,
+                               gather_impl=gather_impl)
 
 
 def sparse_state_bytes(g) -> int:

@@ -45,7 +45,8 @@ from .mesh import (DATA, GRAPH, DATASET_SPEC, DENSE_STATE_SPECS,
 from .policy import PolicyParams, policy_scores
 from .qmodel import scores_local
 from .s2v_sparse import (closed_keep_local, edge_factors,
-                         embed_sparse_local, residual_edge_factors)
+                         embed_sparse_local, residual_edge_factors,
+                         sparse_local_scores)
 
 AXIS = GRAPH     # node-sharding axis name used by the per-layer collectives
 
@@ -129,12 +130,10 @@ def sparse_spatial_scores_fn(mesh: jax.sharding.Mesh, num_layers: int,
         # Edge factors need keep[] of REMOTE neighbor endpoints (paper
         # §5.1's C/S broadcast) — the shared helper all-gathers the local
         # S (and, for "closed", keep) slices over the graph axis.
-        edge_l = edge_factors(nbr_l, valid_l, sol_l, residual, axis=AXIS)
-        emb_l = embed_sparse_local(params.em, nbr_l, edge_l, sol_l,
-                                   num_layers=num_layers, axis=AXIS,
-                                   kernel=kernel, compute=compute,
-                                   gather_impl=gather_impl)
-        local = scores_local(params.q, emb_l, cand_l, axis=AXIS, masked=True)
+        local = sparse_local_scores(params, nbr_l, valid_l, sol_l, cand_l,
+                                    num_layers=num_layers, residual=residual,
+                                    axis=AXIS, kernel=kernel,
+                                    compute=compute, gather_impl=gather_impl)
         return lax.all_gather(local, AXIS, axis=1, tiled=True)
 
     def fn(params, nbr, valid, sol, cand):
@@ -244,13 +243,11 @@ def spatial_train_minibatch_fn(mesh: jax.sharding.Mesh, *,
             my = lax.axis_index(AXIS)
 
             def loss_fn(p):
-                edge_l = edge_factors(nbr_l, val_l, sol_l, residual,
-                                      axis=AXIS)
-                emb_l = embed_sparse_local(p.em, nbr_l, edge_l, sol_l,
-                                           num_layers=num_layers, axis=AXIS,
-                                           kernel=kernel, compute=compute)
-                s_l = scores_local(p.q, emb_l, cand_l, axis=AXIS,
-                                   masked=False)
+                s_l = sparse_local_scores(p, nbr_l, val_l, sol_l, cand_l,
+                                          num_layers=num_layers,
+                                          residual=residual, axis=AXIS,
+                                          masked=False, kernel=kernel,
+                                          compute=compute)
                 return _ownership_loss(s_l, action, target, my, nl, dp)
 
             loss_l, grads_l = jax.value_and_grad(loss_fn)(params)
@@ -403,10 +400,12 @@ def manual_train_minibatch_fn(mesh: jax.sharding.Mesh, *, rep,
                                  masked=masked, kernel=kernel,
                                  compute=compute)
         nbr_t, _val_t, edge_t = topo_t
-        emb = embed_sparse_local(p.em, nbr_t, edge_t, sol_t,
-                                 num_layers=num_layers, axis=AXIS,
-                                 kernel=kernel, compute=compute)
-        return scores_local(p.q, emb, cand_t, axis=AXIS, masked=masked)
+        with jax.named_scope("s2v.embed"):
+            emb = embed_sparse_local(p.em, nbr_t, edge_t, sol_t,
+                                     num_layers=num_layers, axis=AXIS,
+                                     kernel=kernel, compute=compute)
+        with jax.named_scope("q.head"):
+            return scores_local(p.q, emb, cand_t, axis=AXIS, masked=masked)
 
     remat = _dense_remat if dense else _sparse_remat
     replay_specs = (TUPLE_SPEC, P(DATA, GRAPH), TUPLE_SPEC, TUPLE_SPEC,
