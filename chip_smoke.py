@@ -147,7 +147,7 @@ def phase_kernels(*, batch: int, n: int, rho: float, ba_degree: int,
     idx = np.asarray(c.indices)
     ew = np.asarray(c.edge_mask, np.float32) * rng.random(
         idx.shape).astype(np.float32)
-    report_impl("kernels", "dense")
+    report_impl("kernels", "dense", n=n)
     report_impl("kernels", "sparse", max_degree=nbr.shape[2])
     report_impl("kernels", "csr", n=n)
 
@@ -200,7 +200,7 @@ def phase_train(rep: str, *, graphs: int, n: int, steps: int, tau: int,
     kind = "er" if rep == "dense" else "ba"
     adj = random_graph_batch(kind, n, graphs, seed=seed, **graph_kw)
     cfg = dataclasses.replace(cfg, graph_rep=rep, engine="device")
-    shapes = {"dense": {}, "csr": {"n": n},
+    shapes = {"dense": {"n": n}, "csr": {"n": n},
               "sparse": {"max_degree": int((adj > 0).sum(-1).max())}}[rep]
     impl = report_impl("train", rep, **shapes)
     agent = Agent(cfg, num_nodes=n,
@@ -245,7 +245,7 @@ def phase_solve_dense(*, n: int, rho: float, max_evals: int,
     del host
     log(f"  dense N={n}: {edges} edges, graph made and placed in "
         f"{time.perf_counter() - t0:.3f} s")
-    impl = report_impl("solve", "dense")
+    impl = report_impl("solve", "dense", n=n)
     kw = dict(num_layers=cfg.num_layers, multi_node=True, rep="dense")
     t0 = time.perf_counter()
     first = solve(params, adj, max_evals=1, **kw)

@@ -37,7 +37,7 @@ from jax import lax
 
 from ..kernels.backend import VMEM_LIMIT_BYTES, on_tpu
 from ..kernels.s2v_csr import csr_vmem_bytes
-from ..kernels.s2v_fused import dense_vmem_bytes
+from ..kernels.s2v_fused import dense_tiles, dense_vmem_bytes
 from ..kernels.s2v_gather import sparse_vmem_bytes
 
 KERNELS = ("fused", "xla")
@@ -73,19 +73,23 @@ def f32_matmuls(fn):
     return wrapped
 
 
-def s2v_kernel_fits(rep: str, *, k: int, n: int = 0, max_degree: int = 0,
-                    compute_dtype=jnp.float32,
+def s2v_kernel_fits(rep: str, *, k: int, n: int = 0, nl: int = 0,
+                    max_degree: int = 0, compute_dtype=jnp.float32,
                     aggregate_only: bool = False) -> bool:
     """The S2V size rule (ROADMAP S2(a)): True iff the Pallas layer kernel
     of ``rep`` fits the scoped-VMEM budget ``VMEM_LIMIT_BYTES`` at these
-    shapes.  Dense and sparse kernels are tiled, so only K and the max
-    degree D move their footprint; the CSR kernel holds whole (K, N)
-    panels, so its bound is on N.  ``aggregate_only`` selects the
-    aggregation-only kernels (dense ``mp_aggregate``, the sparse gather)."""
+    shapes.  The dense kernels are tiled over both node axes, in the
+    blocks that ``dense_tiles`` picks from K, N and the local rows Nl
+    (``nl``, default N); the sparse kernel's footprint moves with K and
+    the max degree D; the CSR kernel holds whole (K, N) panels, so its
+    bound is on N.  ``aggregate_only`` selects the aggregation-only
+    kernels (dense ``mp_aggregate``, the sparse gather)."""
     epilogue = not aggregate_only
     if rep == "dense":
-        need = dense_vmem_bytes(k, epilogue=epilogue,
-                                compute_dtype=compute_dtype)
+        tn, tl = dense_tiles(k, n, nl or n, epilogue=epilogue,
+                             compute_dtype=compute_dtype)
+        need = dense_vmem_bytes(k, epilogue=epilogue, tile_n=tn,
+                                tile_l=tl, compute_dtype=compute_dtype)
     elif rep == "sparse":
         need = sparse_vmem_bytes(k, max_degree, epilogue=epilogue,
                                  compute_dtype=compute_dtype)
@@ -170,8 +174,9 @@ def _dense_layer_fused(theta4, embed, adj, base, cd):
     """Dispatch for one fused dense layer by :func:`s2v_layer_impl`: the
     Pallas super-kernel on TPU, the jnp composition elsewhere (XLA's
     native fusion beats the interpret-mode kernel off-TPU)."""
-    if s2v_layer_impl("dense", k=embed.shape[1], compute_dtype=cd) \
-            == "pallas":
+    _, k, nl = embed.shape
+    if s2v_layer_impl("dense", k=k, n=adj.shape[2], nl=nl,
+                      compute_dtype=cd) == "pallas":
         return _dense_layer_hw(theta4, embed, adj, base, cd)
     return _dense_layer_jnp(theta4, embed, adj, base, cd)
 
@@ -203,8 +208,9 @@ _agg_hw.defvjp(_agg_hw_fwd, _agg_hw_bwd)
 def _aggregate_fused(embed, adj, cd):
     """Aggregation-only partial (sharded dense path: the psum between
     aggregate and epilogue splits the fusion at the collective)."""
-    if s2v_layer_impl("dense", k=embed.shape[1], compute_dtype=cd,
-                      aggregate_only=True) == "pallas":
+    _, k, nl = embed.shape
+    if s2v_layer_impl("dense", k=k, n=adj.shape[2], nl=nl,
+                      compute_dtype=cd, aggregate_only=True) == "pallas":
         return _agg_hw(embed, adj, cd)
     return _agg_jnp(embed, adj, cd)
 

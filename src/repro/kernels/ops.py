@@ -25,8 +25,8 @@ from .moe_gemm import grouped_glu_ffn as _grouped_glu_ffn
 
 @functools.partial(jax.jit, static_argnames=("tile_n", "tile_l",
                                              "compute_dtype", "interpret"))
-def fused_s2v_layer(theta4, embed, adj, base, *, tile_n: int = 128,
-                    tile_l: int = 128, compute_dtype=jnp.float32,
+def fused_s2v_layer(theta4, embed, adj, base, *, tile_n: int | None = None,
+                    tile_l: int | None = None, compute_dtype=jnp.float32,
                     interpret: bool | None = None):
     """Fused dense structure2vec layer (Alg. 2 lines 11+13-14, one launch)."""
     return _fused_s2v_layer(theta4, embed, adj, base, tile_n=tile_n,
@@ -58,8 +58,9 @@ def fused_s2v_layer_csr(theta4, x, indices, row_ids, edge_w, base, *,
 
 @functools.partial(jax.jit, static_argnames=("tile_n", "tile_l",
                                              "compute_dtype", "interpret"))
-def mp_aggregate(embed, adj, *, tile_n: int = 128, tile_l: int = 128,
-                 compute_dtype=jnp.float32, interpret: bool | None = None):
+def mp_aggregate(embed, adj, *, tile_n: int | None = None,
+                 tile_l: int | None = None, compute_dtype=jnp.float32,
+                 interpret: bool | None = None):
     """Aggregation-only partial kernel for the sharded dense path (the psum
     between aggregate and epilogue splits the fusion at the collective)."""
     return _mp_aggregate(embed, adj, tile_n=tile_n, tile_l=tile_l,

@@ -22,6 +22,8 @@ from repro.core.s2v import (_dense_layer_hw, _dense_layer_jnp, _agg_hw,
                             _agg_jnp, check_kernel, compute_dtype)
 from repro.core.s2v_sparse import _sparse_layer_hw, _sparse_layer_jnp
 from repro.kernels import ops, ref
+from repro.kernels.s2v_fused import (DENSE_VMEM_BUDGET, dense_tiles,
+                                     dense_vmem_bytes)
 
 RNG = np.random.default_rng(7)
 REPS = ("dense", "sparse")
@@ -73,6 +75,57 @@ def test_fused_dense_kernel_vs_oracle(compute, tile):
     want = np.asarray(ref.s2v_layer(t4, embed, adj, base))
     tol = BF16_TOL if compute == "bf16" else dict(rtol=1e-6, atol=1e-6)
     np.testing.assert_allclose(out, want, **tol)
+
+
+# (Nl, N, tile_n, tile_l): the shape-chosen blocks, and explicit blocks
+# that leave a partial last block on both node axes.  Interpret mode fills
+# the part of a block past the array with NaN, so these fail if the
+# kernels' edge mask is missing.
+DENSE_BLOCKS = [(300, 300, None, None), (300, 300, 256, 128),
+                (200, 300, None, None), (200, 300, 256, 128)]
+
+
+@pytest.mark.parametrize("kernel", ["fused", "aggregate"])
+@pytest.mark.parametrize("compute", ["f32", "bf16"])
+@pytest.mark.parametrize("nl,n,tile_n,tile_l", DENSE_BLOCKS)
+def test_dense_kernels_blocks_vs_oracle(kernel, compute, nl, n, tile_n,
+                                        tile_l):
+    b, k = 2, 16
+    embed = _rand((b, k, nl))
+    adj = (RNG.random((b, nl, n)) < 0.3).astype(np.float32)
+    cd = compute_dtype(compute)
+    if kernel == "fused":
+        t4, base = _rand((k, k)) * 0.2, _rand((b, k, n))
+        out = ops.fused_s2v_layer(t4, embed, adj, base, tile_n=tile_n,
+                                  tile_l=tile_l, compute_dtype=cd)
+        want = ref.s2v_layer(t4, embed, adj, base)
+    else:
+        out = ops.mp_aggregate(embed, adj, tile_n=tile_n, tile_l=tile_l,
+                               compute_dtype=cd)
+        want = ref.mp_aggregate(embed, adj)
+    assert out.shape == (b, k, n)
+    tol = BF16_TOL if compute == "bf16" else dict(rtol=1e-5, atol=1e-5)
+    np.testing.assert_allclose(np.asarray(out), np.asarray(want), **tol)
+
+
+def test_dense_tiles_follow_the_shapes():
+    """Legal TPU blocks (the full dim or a multiple of 128): one block per
+    graph at the train shape, and a grid of hundreds of steps, not tens of
+    thousands, at the paper's largest graph (W1, N=21,000)."""
+    k = 32
+    for cd in (jnp.float32, jnp.bfloat16):
+        for epilogue in (True, False):
+            assert dense_tiles(k, 1024, 1024, epilogue=epilogue,
+                               compute_dtype=cd) == (1024, 1024)
+            for nl, n in ((21_000, 21_000), (5_250, 21_000)):
+                tn, tl = dense_tiles(k, n, nl, epilogue=epilogue,
+                                     compute_dtype=cd)
+                assert tn == n or tn % 128 == 0
+                assert tl == nl or tl % 128 == 0
+                assert -(-n // tn) * -(-nl // tl) <= 1000
+                assert dense_vmem_bytes(
+                    k, epilogue=epilogue, tile_n=tn, tile_l=tl,
+                    compute_dtype=cd) <= DENSE_VMEM_BUDGET
 
 
 @pytest.mark.parametrize("compute", ["f32", "bf16"])

@@ -11,12 +11,14 @@ The topology is described only inside the module fixture, never while
 this file is imported, so pytest-xdist workers all collect the same
 tests and only the worker given this file loads the TPU compiler.
 """
+import re
+
 import jax
 import jax.numpy as jnp
 import pytest
 
 from repro.core.s2v import s2v_kernel_fits
-from repro.kernels import s2v_csr, s2v_fused, s2v_gather
+from repro.kernels import ops, s2v_csr, s2v_fused, s2v_gather
 
 K = 32
 # (B, N, D, E) per shape; D and E are the BA d=4 / d=10 maxima from
@@ -147,7 +149,33 @@ def test_size_rule_matches_compiler(one_chip, kernel, rep, compute, axis):
 
 
 def test_dense_kernels_fit_at_every_n():
-    """The dense kernels are tiled over both node axes: their VMEM need is
-    independent of N, so the rule admits them at any graph size."""
-    for agg in (False, True):
-        assert s2v_kernel_fits("dense", k=K, aggregate_only=agg)
+    """The dense kernels take the blocks that ``dense_tiles`` picks from
+    K, N and Nl within a budget inside the limit, so the rule, counting
+    those same blocks, admits them at the train, W1 and W1-sp4 shapes."""
+    for shape in ("train", "w1", "w1_sp4"):
+        n = SHAPES[shape]["n"]
+        nl = SHAPES[shape].get("nl", n)
+        for cd in (jnp.float32, jnp.bfloat16):
+            for agg in (False, True):
+                assert s2v_kernel_fits("dense", k=K, n=n, nl=nl,
+                                       compute_dtype=cd, aggregate_only=agg)
+
+
+def test_w1_dense_kernel_reads_the_adjacency_unpadded(one_chip):
+    """At W1 the dense layer is one custom call, named after the jit
+    wrapper ``fused_s2v_layer`` (the roofline reader finds the kernel by
+    that name and reads the work from its shapes), whose adjacency operand
+    is the (1, N, N) array itself: the program holds no pad."""
+    n = SHAPES["w1"]["n"]
+    s = lambda *shape: _spec(one_chip, shape)
+    text = ops.fused_s2v_layer.lower(
+        s(K, K), s(1, K, n), s(1, n, n), s(1, K, n),
+        interpret=False).compile().as_text()
+    assert not re.search(r"\bpad\(", text)
+    calls = re.findall(r"(%[\w.]+) = \S+ custom-call\(([^)]*)\)", text)
+    assert len(calls) == 1
+    name, operands = calls[0]
+    assert name.startswith("%fused_s2v_layer.")
+    adj = operands.split(", ")[2]
+    shape = re.search(rf"^\s*{re.escape(adj)} = (\S+) ", text, re.M)
+    assert shape and shape.group(1).startswith(f"f32[1,{n},{n}]")
