@@ -363,13 +363,14 @@ def phase_mesh_solve(*, n: int, rho: float, spatial: tuple, max_evals: int,
     """Dense policy scores at ``spatial`` against the one-device reference,
     relative to the scores' magnitude (they grow as N³ with random
     weights: about 1e5 at N=21,000, where one f32 ulp is 8e-3), then a
-    capped solve on the mesh.  Near-tied scores make the top-d picks of
-    the two solves differ by rounding, so the solve reports, and does not
-    gate on, the nodes where they differ."""
+    capped solve on the mesh from an adjacency split by rows, each device
+    holding only its (1, N/sp, N) block.  Near-tied scores make the top-d
+    picks of the two solves differ by rounding, so the solve reports, and
+    does not gate on, the nodes where they differ."""
     from jax.sharding import NamedSharding
     from repro.core import make_mesh, policy_scores, spatial_scores_fn
     from repro.core.graphs import init_state
-    from repro.core.mesh import DENSE_STATE_SPECS
+    from repro.core.mesh import DENSE_STATE_SPECS, shard_rows
     params = init_policy(jax.random.key(seed), cfg)
     adj = erdos_renyi(n, rho, seed=seed)[None]
     st = init_state(jax.device_put(adj, jax.devices()[0]))
@@ -392,8 +393,15 @@ def phase_mesh_solve(*, n: int, rho: float, spatial: tuple, max_evals: int,
     kw = dict(num_layers=cfg.num_layers, multi_node=True, rep="dense",
               max_evals=max_evals)
     ref_res = solve(params, adj, **kw)
+    # the solve's input split by rows as its carry holds it: (B/dp, N/sp,
+    # N) on each device, never the whole graph
+    rows = shard_rows(mesh, adj)
+    shapes = _shard_shapes(f"dense solve input at {spatial}", rows)
+    want = (1, n // spatial[1], n)
+    check(all(sh == want for _, sh in shapes),
+          f"mesh solve input shards {shapes}, want {want} on each device")
     t0 = time.perf_counter()
-    res = solve(params, adj, spatial=spatial, **kw)
+    res = solve(params, rows, spatial=spatial, **kw)
     wall = time.perf_counter() - t0
     differ = int((res.solution != ref_res.solution).sum())
     log(f"  capped solve at {spatial}: {res.policy_evals} evals in "

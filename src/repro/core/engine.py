@@ -53,8 +53,8 @@ from .agent import max_q_from_scores, train_minibatch_raw
 from .graphrep import GraphRep, get_rep
 from .inference import apply_selection
 from .mesh import (DATA, MeshSpec, constrain_batch, constrain_dataset,
-                   constrain_replay, make_mesh, normalize_spatial,
-                   resolve_collectives, shard_replay)
+                   constrain_replay, constrain_solve_state, make_mesh,
+                   normalize_spatial, resolve_collectives, shard_replay)
 from .policy import PolicyConfig, PolicyParams
 from .qmodel import NEG_INF
 from .replay import (DeviceReplay, device_replay_init, device_replay_push,
@@ -386,10 +386,14 @@ def get_solve_step(*, rep: Union[str, GraphRep, None] = None,
     back-compats to ``(1, P)``, DESIGN.md §10): the while_loop runs with
     the batch sharded over ``data`` — B/dp graphs per device, the done
     check reduced over the mesh — and each policy evaluation partitioned
-    sp-way under shard_map (dense row blocks / sparse neighbor-list rows;
-    same per-eval collectives as the 1-D spatial path, DESIGN.md §3),
-    with the top-d commit running data-parallel in the paper's Fig. 4
-    lockstep.  ``max_d`` widens the adaptive top-d cap beyond the paper's
+    sp-way under shard_map (same per-eval collectives as the 1-D spatial
+    path, DESIGN.md §3), with the top-d commit in the paper's Fig. 4
+    lockstep.  A dense adjacency keeps its rows split over ``graph`` in
+    the carry for the whole solve (``mesh.DENSE_SOLVE_SPECS``): each
+    device scores, selects and commits on its own rows
+    (``spatial.dense_solve_eval_fn``) and none holds the whole graph.  The
+    sparse rep's neighbor lists are retiled over ``graph`` per
+    evaluation.  ``max_d`` widens the adaptive top-d cap beyond the paper's
     8 for paper-scale solves (see ``inference.solve``).  ``donate=True``
     donates the solve state into the while_loop (callers never reuse it
     — every solve builds the state fresh); ``False`` keeps the copying
@@ -406,6 +410,7 @@ def _build_solve_step(rep: GraphRep, problem: str, num_layers: int,
                       use_adaptive: bool, spatial: tuple, kernel: str,
                       compute: str, max_d: int, donate: bool = True):
     dp, sp = spatial
+    mesh = evaluate = None
     if (dp, sp) != (1, 1):
         _check_csr_spatial(rep, sp)
         mesh = make_mesh(dp, sp)
@@ -414,6 +419,14 @@ def _build_solve_step(rep: GraphRep, problem: str, num_layers: int,
             # with the batch over `data`.
             score_fn = per_graph_scorer(mesh, rep, num_layers=num_layers,
                                         kernel=kernel, compute=compute)
+        elif rep.name == "dense":
+            # the adjacency rows stay split over `graph` for the whole
+            # solve: score, select and commit run on the resident rows
+            from .spatial import dense_solve_eval_fn
+            evaluate = dense_solve_eval_fn(
+                mesh, problem=problem, num_layers=num_layers,
+                use_adaptive=use_adaptive, max_d=max_d, kernel=kernel,
+                compute=compute)
         else:
             from .spatial import spatial_solve_scores_fn
             score_fn = spatial_solve_scores_fn(
@@ -421,11 +434,17 @@ def _build_solve_step(rep: GraphRep, problem: str, num_layers: int,
                 residual=env_lib.sparse_residual_flag(problem),
                 kernel=kernel, compute=compute)
     else:
-        mesh = None
-
         def score_fn(params, state):
             return rep.scores(params, state, num_layers=num_layers,
                               kernel=kernel, compute=compute)
+
+    if evaluate is None:
+        def evaluate(params, state):
+            # env-polymorphic select → prune → commit, shared verbatim
+            # with the host-loop step (bit-identical engines)
+            return apply_selection(state, score_fn(params, state),
+                                   state.candidate, use_adaptive, problem,
+                                   max_d)
 
     # Donate the init state into the while_loop: every caller builds the
     # state fresh per solve (inference.solve, serving._dispatch) and never
@@ -433,9 +452,9 @@ def _build_solve_step(rep: GraphRep, problem: str, num_layers: int,
     @functools.partial(jax.jit, donate_argnums=(1,) if donate else ())
     def solve_fn(params, state, max_evals):
         if mesh is not None:
-            # B/dp graphs per device through the whole while_loop; the
-            # spatial scorer retiles node rows over `graph` per eval.
-            state = constrain_batch(mesh, state)
+            # the carry's layout (mesh.solve_state_specs): B/dp graphs per
+            # device, and a dense adjacency's rows over `graph`
+            state = constrain_solve_state(mesh, state)
         b = state.candidate.shape[0]
 
         def cond(carry):
@@ -446,12 +465,7 @@ def _build_solve_step(rep: GraphRep, problem: str, num_layers: int,
 
         def body(carry):
             state, evals, committed, _done = carry
-            scores = score_fn(params, state)
-            # env-polymorphic select → prune → commit, shared verbatim
-            # with the host-loop step (bit-identical engines)
-            new_state, done, ncommit = apply_selection(
-                state, scores, state.candidate, use_adaptive, problem,
-                max_d)
+            new_state, done, ncommit = evaluate(params, state)
             return (new_state, evals + 1, committed + ncommit, done)
 
         init = (state, jnp.int32(0), jnp.zeros((b,), jnp.int32),

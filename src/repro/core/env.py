@@ -54,7 +54,7 @@ import jax.numpy as jnp
 from jax import lax
 
 from .graphs import (CsrGraphState, GraphState, SparseGraphState,
-                     closed_neighborhood_keep, closed_neighborhood_keep_dense,
+                     closed_neighborhood_keep,
                      csr_closed_neighborhood_keep, csr_residual_edge_mask,
                      csr_row_ids, csr_segment_max, csr_segment_sum,
                      init_state, residual_edge_mask)
@@ -469,11 +469,12 @@ def mis_commit(state, sel: jax.Array):
         return SparseGraphState(neighbors=state.neighbors, valid=state.valid,
                                 candidate=candidate, solution=solution,
                                 residual=state.residual), done
-    keep = closed_neighborhood_keep_dense(state.adj, sel)
-    adj = state.adj * keep[:, :, None] * keep[:, None, :]
+    keep = state.closed_keep(sel)
+    adj = state.masked(keep)
     candidate = state.candidate * keep
     done = candidate.sum(-1) == 0
-    return GraphState(adj=adj, candidate=candidate, solution=solution), done
+    return dataclasses.replace(state, adj=adj, candidate=candidate,
+                               solution=solution), done
 
 
 def mis_prune(state, sel: jax.Array, scores: jax.Array) -> jax.Array:
@@ -497,8 +498,7 @@ def mis_prune(state, sel: jax.Array, scores: jax.Array) -> jax.Array:
             return closed_neighborhood_keep(state.neighbors, state.valid,
                                             pick)
     else:
-        def keep_fn(pick):
-            return closed_neighborhood_keep_dense(state.adj, pick)
+        keep_fn = state.closed_keep
 
     def body(carry, _):
         kept, active = carry
@@ -561,9 +561,8 @@ def _covered_and_need(state):
         s_nbr = jax.vmap(lambda sb, nb: sb[nb])(sol_pad, state.neighbors)
         cov_nbr = (val * s_nbr).max(-1)
     else:
-        deg0 = state.adj.sum(-1)
-        cov_nbr = (jnp.einsum("bnm,bm->bn", state.adj, sol) > 0
-                   ).astype(jnp.float32)
+        deg0 = state.degree()
+        cov_nbr = (state.matvec(sol) > 0).astype(jnp.float32)
     covered = jnp.maximum(sol, cov_nbr)
     return covered, deg0 > 0
 
@@ -587,7 +586,7 @@ def mds_candidates(state) -> jax.Array:
         u_nbr = jax.vmap(lambda ub, nb: ub[nb])(u_pad, state.neighbors)
         gain = uncov + (val * u_nbr).sum(-1)
     else:
-        gain = uncov + jnp.einsum("bnm,bm->bn", state.adj, uncov)
+        gain = uncov + state.matvec(uncov)
     return ((state.solution < 0.5) & (gain > 0)).astype(jnp.float32)
 
 

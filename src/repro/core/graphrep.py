@@ -132,19 +132,31 @@ class DenseRep(GraphRep):
 
     def scores(self, params, state: GraphState, *, num_layers,
                masked=True, kernel="fused", compute="f32") -> jax.Array:
-        return policy_scores(params, state.adj, state.solution,
-                             state.candidate, num_layers=num_layers,
-                             masked=masked, kernel=kernel, compute=compute)
+        """(B, N) scores.  On a row-split state (``state.row_axis``) each
+        device scores its resident rows, with one collective per layer
+        over the row axis (Alg. 2), and the scores are gathered whole so
+        every device takes the same top-d (Alg. 4 line 6)."""
+        local = policy_scores(params, state.adj,
+                              state.local_rows(state.solution),
+                              state.local_rows(state.candidate),
+                              num_layers=num_layers, axis=state.row_axis,
+                              masked=masked, kernel=kernel, compute=compute)
+        if state.row_axis is None:
+            return local
+        with jax.named_scope("q.head"):
+            return state.all_rows(local)
 
     def commit(self, state: GraphState, sel):
+        """Remove the selected nodes' rows and columns; on a row-split
+        state each device rewrites only its resident rows."""
         solution = jnp.maximum(state.solution, sel)
         keep = 1.0 - sel
-        adj = state.adj * keep[:, :, None] * keep[:, None, :]
-        deg = adj.sum(-1)
+        new = dataclasses.replace(state, adj=state.masked(keep),
+                                  solution=solution)
+        deg = new.degree()
         candidate = ((deg > 0) & (solution < 0.5)).astype(jnp.float32)
-        done = adj.sum((-1, -2)) == 0
-        return GraphState(adj=adj, candidate=candidate,
-                          solution=solution), done
+        done = new.total() == 0
+        return dataclasses.replace(new, candidate=candidate), done
 
     def state_bytes(self, state: GraphState) -> int:
         return int(state.adj.size * state.adj.dtype.itemsize

@@ -127,10 +127,19 @@ class GraphState:
                the partial solution are zeroed, paper Fig 4 right panel).
     candidate: (B, N) float mask — the paper's C vector.
     solution:  (B, N) float mask — the paper's S vector.
+    row_axis:  None, or the ``shard_map`` axis over which ``adj``'s rows
+               are split (paper §5.3 distributed storage): ``adj`` is then
+               this device's (B, N/P, N) row block, while ``candidate`` and
+               ``solution`` stay whole.  The methods below are every
+               adjacency access of the env commit rules; with a row axis
+               each works on the resident rows and exchanges (B, N)
+               vectors, so no device ever holds another's rows.
     """
     adj: jax.Array
     candidate: jax.Array
     solution: jax.Array
+    row_axis: Optional[str] = dataclasses.field(
+        default=None, metadata=dict(static=True))
 
     @property
     def batch(self) -> int:
@@ -139,6 +148,49 @@ class GraphState:
     @property
     def num_nodes(self) -> int:
         return self.adj.shape[-1]
+
+    def local_rows(self, v: jax.Array) -> jax.Array:
+        """The resident rows' entries of a whole (B, N) vector."""
+        if self.row_axis is None:
+            return v
+        nl = self.adj.shape[1]
+        return jax.lax.dynamic_slice_in_dim(
+            v, jax.lax.axis_index(self.row_axis) * nl, nl, axis=1)
+
+    def all_rows(self, x: jax.Array) -> jax.Array:
+        """The whole (B, N) vector from each device's (B, N/P) rows."""
+        if self.row_axis is None:
+            return x
+        with jax.named_scope("mesh.collective"):
+            return jax.lax.all_gather(x, self.row_axis, axis=1, tiled=True)
+
+    def degree(self) -> jax.Array:
+        """(B, N) row sums of the adjacency."""
+        return self.all_rows(self.adj.sum(-1))
+
+    def matvec(self, v: jax.Array) -> jax.Array:
+        """(B, N) product of the adjacency with a whole (B, N) vector."""
+        return self.all_rows(jnp.einsum("bnm,bm->bn", self.adj, v))
+
+    def masked(self, keep: jax.Array) -> jax.Array:
+        """The adjacency with the rows and columns where ``keep`` is 0
+        zeroed: A * keep keepᵀ (only the resident rows)."""
+        return (self.adj * self.local_rows(keep)[:, :, None]
+                * keep[:, None, :])
+
+    def total(self) -> jax.Array:
+        """(B,) sum of every adjacency entry (twice the edge count)."""
+        t = self.adj.sum((-1, -2))
+        if self.row_axis is None:
+            return t
+        with jax.named_scope("mesh.collective"):
+            return jax.lax.psum(t, self.row_axis)
+
+    def closed_keep(self, sel: jax.Array) -> jax.Array:
+        """Keep factors (B, N) after removing ``sel`` and its neighbours
+        (:func:`closed_neighborhood_keep_dense` on this state)."""
+        nbr_s = self.matvec(sel)
+        return (1.0 - sel) * (1.0 - (nbr_s > 0).astype(jnp.float32))
 
 
 def init_state(adj: jax.Array) -> GraphState:

@@ -223,14 +223,11 @@ def _solve_fused(params: PolicyParams, adj0, *, rep: GraphRep, problem: str,
     placement), ``solve.dispatch`` (the call into the fused program) and
     ``solve.fetch`` (the ``device_get`` of the answer)."""
     from .engine import get_solve_step
-    from .mesh import make_mesh, normalize_spatial, shard_batch
+    from .mesh import (make_mesh, normalize_spatial, shard_rows,
+                       shard_solve_state)
     with jax.profiler.TraceAnnotation("solve.prepare"):
-        state = init_solve_state(rep, adj0, problem)
-        max_evals = max_evals or (state.num_nodes + max_d)
         dp, sp = normalize_spatial(spatial)
-        if state.batch % dp:
-            raise ValueError(f"batch {state.batch} not divisible by the "
-                             f"data-axis size {dp} of mesh spec {spatial!r}")
+        multi = (dp, sp) != (1, 1)
         # Donating the solve state is only safe when it does not alias the
         # caller's arrays: a prebuilt sparse/csr batch (or state) shares
         # its topology buffers with the state init_solve_state returns,
@@ -239,16 +236,27 @@ def _solve_fused(params: PolicyParams, adj0, *, rep: GraphRep, problem: str,
                  ("indptr", "indices", "edge_mask", "neighbors", "valid",
                   "solution", "candidate") if getattr(adj0, f, None)
                  is not None} | {id(adj0)}
+        graph = adj0
+        if multi and rep.name == "dense" and hasattr(adj0, "ndim"):
+            # a dense adjacency goes to the devices by rows before any work
+            # on it, and is never gathered whole onto one device (a copy
+            # unless the caller placed it so)
+            graph = shard_rows(make_mesh(dp, sp), adj0)
+        state = init_solve_state(rep, graph, problem)
+        max_evals = max_evals or (state.num_nodes + max_d)
+        if state.batch % dp:
+            raise ValueError(f"batch {state.batch} not divisible by the "
+                             f"data-axis size {dp} of mesh spec {spatial!r}")
         shares = any(id(x) in owned for x in jax.tree.leaves(state))
         fused = get_solve_step(rep=rep, problem=problem,
                                num_layers=num_layers,
                                use_adaptive=multi_node, spatial=spatial,
                                kernel=kernel, compute=compute, max_d=max_d,
                                donate=not shares)
-        if (dp, sp) != (1, 1):
-            # batch-sharded placement up front: the while_loop's resident
-            # layout, so the donated state buffers alias from call one
-            state = shard_batch(make_mesh(dp, sp), state)
+        if multi:
+            # the while_loop's resident layout up front, so the donated
+            # state buffers alias from call one
+            state = shard_solve_state(make_mesh(dp, sp), state)
     with jax.profiler.TraceAnnotation("solve.dispatch"):
         out, evals, committed = fused(params, state,
                                       jnp.asarray(max_evals, jnp.int32))

@@ -21,6 +21,7 @@ the device replay buffer) lays out on it — batch dim sharded over
 | solution / candidate (B, N) | ``P(data, graph)`` | ``P(data, graph)`` |
 | scores out of a spatial eval | ``P(data)`` (replicated over ``graph`` post all-gather) | same |
 | replay tuples (R, ·) | rows over ``data``, S masks ``P(data, graph)`` | same |
+| fused solve carry | ``adj P(data, graph, None)``, masks ``P(data)`` (``DENSE_SOLVE_SPECS``) | every array ``P(data)`` |
 
 Back-compat rule: ``PolicyConfig.spatial`` historically was an int P
 meaning "P-way node sharding".  ``normalize_spatial`` keeps that contract
@@ -219,12 +220,68 @@ def constrain_batch(mesh, state):
     """Constrain ONLY the batch dim of every state array over ``data``.
 
     This is the layout of replicated-per-node phases (acting, the fused
-    solve's commit/done bookkeeping): per-graph rows stay whole so their
+    train step's bookkeeping): per-graph rows stay whole so their
     arithmetic is bit-identical to the single-device path, while the batch
     splits dp ways; the node axis is tiled over ``graph`` only inside the
     spatial ``shard_map`` evaluations."""
     specs = {name: P(DATA) for name in state_field_specs(state)}
     return _apply(mesh, state, specs, jax.lax.with_sharding_constraint)
+
+
+# The fused solve's carry on a mesh (paper §5.3 distributed storage): a
+# dense state keeps its adjacency rows over `graph` for the whole solve,
+# N/sp resident rows per device, with its (B, N) masks whole on every
+# device of a graph group; the other reps' states split by batch only.
+DENSE_SOLVE_SPECS = {"adj": P(DATA, GRAPH, None), "candidate": P(DATA),
+                     "solution": P(DATA)}
+
+
+def solve_state_specs(state) -> dict:
+    """Field-name → PartitionSpec of a state in the fused solve's carry."""
+    from .graphs import GraphState
+    if isinstance(state, GraphState):
+        return DENSE_SOLVE_SPECS
+    return {name: P(DATA) for name in state_field_specs(state)}
+
+
+def shard_rows(mesh, adj):
+    """A dense (B, N, N) or (N, N) adjacency laid out as the solve carry
+    holds it: rows over ``graph``, batch over ``data``.  An array already
+    so laid out is returned as it is; any other is placed by rows, so no
+    device receives rows that are not its own."""
+    sharding = NamedSharding(mesh, DENSE_SOLVE_SPECS["adj"])
+    if adj.ndim == 2:
+        adj = adj[None]
+    dp, sp = mesh_shape(mesh)
+    if adj.shape[0] % dp or adj.shape[1] % sp:
+        raise ValueError(f"dense adjacency {tuple(adj.shape)}: batch not "
+                         f"divisible by the data-axis size {dp} or rows "
+                         f"by the graph-axis size {sp}")
+    if isinstance(adj, jax.Array) and adj.sharding.is_equivalent_to(
+            sharding, adj.ndim):
+        return adj
+    return jax.device_put(adj, sharding)
+
+
+def shard_solve_state(mesh, state):
+    """Host-side placement of a fresh solve state in the carry's layout
+    (:func:`solve_state_specs`), so the state buffers the fused solve
+    donates alias its carry from the first call.  A dense adjacency is
+    placed with :func:`shard_rows`; the other reps as :func:`shard_batch`
+    places them."""
+    from .graphs import GraphState
+    if not isinstance(state, GraphState):
+        return shard_batch(mesh, state)
+    masks = {k: v for k, v in DENSE_SOLVE_SPECS.items() if k != "adj"}
+    state = _apply(mesh, state, masks, jax.device_put)
+    return dataclasses.replace(state, adj=shard_rows(mesh, state.adj))
+
+
+def constrain_solve_state(mesh, state):
+    """jit-traceable ``with_sharding_constraint`` version of
+    :func:`shard_solve_state`: the fused solve pins its carry with it."""
+    return _apply(mesh, state, solve_state_specs(state),
+                  jax.lax.with_sharding_constraint)
 
 
 def shard_replay(mesh, replay):

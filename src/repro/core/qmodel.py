@@ -52,7 +52,8 @@ def scores_local(
     # Lines 4-5: global graph embedding sum (all-reduce of B×K)
     sum_embed = embed_local.sum(-1)                          # (B, K)
     if axis is not None:
-        sum_embed = lax.psum(sum_embed, axis)
+        with jax.named_scope("mesh.collective"):
+            sum_embed = lax.psum(sum_embed, axis)
     # Line 6: w1 = θ5 @ Σ embed
     w1 = jnp.einsum("kj,bj->bk", params.theta5, sum_embed)   # (B, K)
     # Lines 8-9: candidate extraction (sparse diag) then θ6 projection

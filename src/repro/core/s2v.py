@@ -261,7 +261,8 @@ def embed_local(
                 # (cheap, Nl-local) epilogue — keeps cross-mesh numerics
                 # identical to the collective placement of the xla chain.
                 nbr_partial = _aggregate_fused(embed, adj_local, cd)
-                nbr_full = lax.psum(nbr_partial, axis)       # Line 12
+                with jax.named_scope("mesh.collective"):
+                    nbr_full = lax.psum(nbr_partial, axis)   # Line 12
                 nbr_local = lax.dynamic_slice_in_dim(nbr_full, my * nl, nl,
                                                      axis=2)
                 e3 = jnp.einsum("kj,bjn->bkn", params.theta4.astype(cd),
@@ -274,7 +275,8 @@ def embed_local(
             nbr_partial = jnp.einsum("bkl,bln->bkn", embed, adj_local)
             if axis is not None:
                 # Line 12: MPI_All_reduce of the (B, K, N) buffer
-                nbr_full = lax.psum(nbr_partial, axis)
+                with jax.named_scope("mesh.collective"):
+                    nbr_full = lax.psum(nbr_partial, axis)
                 nbr_local = lax.dynamic_slice_in_dim(nbr_full, my * nl, nl,
                                                      axis=2)
             else:

@@ -19,6 +19,13 @@ slices.  Per embedding layer the (B/dp, K, N) embedding buffer is
 all-gathered over ``graph`` so local gathers can reach remote-resident
 neighbors (DESIGN.md §3).
 
+``dense_solve_eval_fn`` is one evaluation of the fused dense solve on
+the paper's distributed storage (§5.3): the adjacency rows stay split
+over ``graph`` for the whole solve, and scoring, top-d selection and the
+commit all run under one shard_map on the resident rows (the commit
+rewrites only them).  ``spatial_solve_scores_fn`` is the sparse rep's
+per-evaluation scorer for the same loop.
+
 ``spatial_train_minibatch_fn`` is Alg. 5's per-GPU gradient descent with
 the MPI_All_reduce generalized to the 2-D mesh: every (data, graph) tile
 owns the TD-error terms of its local batch rows whose action node resides
@@ -30,6 +37,7 @@ layout, so every pre-mesh caller keeps working unchanged.
 """
 from __future__ import annotations
 
+import dataclasses
 import functools
 from typing import Optional
 
@@ -38,10 +46,10 @@ import jax.numpy as jnp
 from jax import lax
 from jax.sharding import PartitionSpec as P
 
-from .mesh import (DATA, GRAPH, DATASET_SPEC, DENSE_STATE_SPECS,
-                   SPARSE_STATE_SPECS, SCORES_SPEC, TUPLE_SPEC, make_mesh,
-                   mesh_shape, per_device_bytes, sparse_per_device_bytes,
-                   state_field_specs)   # noqa: F401
+from .mesh import (DATA, GRAPH, DATASET_SPEC, DENSE_SOLVE_SPECS,
+                   DENSE_STATE_SPECS, SPARSE_STATE_SPECS, SCORES_SPEC,
+                   TUPLE_SPEC, make_mesh, mesh_shape, per_device_bytes,
+                   sparse_per_device_bytes, state_field_specs)  # noqa: F401
 from .policy import PolicyParams, policy_scores
 from .qmodel import scores_local
 from .s2v_sparse import (closed_keep_local, edge_factors,
@@ -146,26 +154,64 @@ def sparse_spatial_scores_fn(mesh: jax.sharding.Mesh, num_layers: int,
 def spatial_solve_scores_fn(mesh: jax.sharding.Mesh, *, num_layers: int,
                             rep, residual=True, kernel: str = "fused",
                             compute: str = "f32"):
-    """State-in, scores-out wrapper around the mesh-partitioned scorers for
-    the FUSED solve loop (DESIGN.md §9): takes the solve state (batch
-    sharded over ``data`` by the engine), reshards its arrays onto the
-    mesh's (data, graph) tiling inside jit, runs one spatially-partitioned
-    policy evaluation (per-eval collectives over ``graph`` unchanged from
-    the 1-D path), and returns the all-gathered (B, N) scores replicated
-    over ``graph`` so the top-d commit runs in the paper's Fig. 4 lockstep
-    — data-parallel over the batch, replicated over node shards.
+    """State-in, scores-out wrapper around the sparse mesh-partitioned
+    scorer for the FUSED solve loop (DESIGN.md §9): takes the solve state
+    (batch sharded over ``data`` by the engine), reshards its neighbor
+    lists onto the mesh's (data, graph) tiling inside jit, runs one
+    spatially-partitioned policy evaluation, and returns the all-gathered
+    (B, N) scores replicated over ``graph`` so the top-d commit runs in
+    the paper's Fig. 4 lockstep.  The dense rep keeps its rows split for
+    the whole solve instead (:func:`dense_solve_eval_fn`).
     """
-    if rep.name == "sparse":
-        scorer = sparse_spatial_scores_fn(mesh, num_layers,
-                                          residual=residual, kernel=kernel,
-                                          compute=compute)
-        return lambda params, state: scorer(params, state.neighbors,
-                                            state.valid, state.solution,
-                                            state.candidate)
-    scorer = spatial_scores_fn(mesh, num_layers, kernel=kernel,
-                               compute=compute)
-    return lambda params, state: scorer(params, state.adj, state.solution,
+    if rep.name != "sparse":
+        raise ValueError(f"spatial_solve_scores_fn scores the sparse rep; "
+                         f"got {rep.name!r}")
+    scorer = sparse_spatial_scores_fn(mesh, num_layers, residual=residual,
+                                      kernel=kernel, compute=compute)
+    return lambda params, state: scorer(params, state.neighbors,
+                                        state.valid, state.solution,
                                         state.candidate)
+
+
+def dense_solve_eval_fn(mesh: jax.sharding.Mesh, *, problem: str,
+                        num_layers: int, use_adaptive: bool, max_d: int,
+                        kernel: str = "fused", compute: str = "f32"):
+    """One policy evaluation of the fused solve on a dense state whose
+    adjacency rows stay split over ``graph`` (paper §5.1 spatial
+    parallelism on §5.3 distributed storage; DESIGN.md §10).
+
+    Returns ``fn(params, state) -> (state', done, committed)`` on the
+    carry's layout (``mesh.DENSE_SOLVE_SPECS``): each device holds the
+    (B/dp, N/sp, N) rows of its nodes and the whole (B/dp, N) masks.  Under
+    one ``shard_map`` it scores its rows (one (B, K, N) ``psum`` per layer
+    over ``graph``), gathers the (B, N) scores so every device of a graph
+    group takes the same top-d, and runs the env's selection and commit
+    rules on a state whose ``row_axis`` is ``graph``: the commit rewrites
+    only the resident rows, and the done check reduces over ``graph``."""
+    from ..sharding.compat import shard_map_nocheck
+    from .graphrep import DENSE
+    from .graphs import GraphState
+    from .inference import apply_selection
+
+    specs = GraphState(**DENSE_SOLVE_SPECS)
+
+    @functools.partial(shard_map_nocheck, mesh=mesh, in_specs=(P(), specs),
+                       out_specs=(specs, P(DATA), P(DATA)))
+    def evaluate(params, state):
+        state = dataclasses.replace(state, row_axis=AXIS)
+        scores = DENSE.scores(params, state, num_layers=num_layers,
+                              kernel=kernel, compute=compute)
+        new, done, committed = apply_selection(state, scores,
+                                               state.candidate, use_adaptive,
+                                               problem, max_d)
+        return dataclasses.replace(new, row_axis=None), done, committed
+
+    def fn(params, state):
+        _check_divisible(mesh, state.adj.shape[0], state.adj.shape[1],
+                         "dense solve")
+        return evaluate(params, state)
+
+    return fn
 
 
 def _ownership_loss(s_l, action, target, my, nl, dp):

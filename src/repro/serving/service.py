@@ -364,13 +364,14 @@ class GraphSolverService:
         return fn
 
     def _place(self, state):
-        """Batch-sharded mesh placement of a fresh solve state — the
-        layout the fused solve keeps it in, so the state buffers it
-        donates alias the while_loop carry from the first call."""
+        """Mesh placement of a fresh solve state — the layout the fused
+        solve keeps it in (``mesh.solve_state_specs``), so the state
+        buffers it donates alias the while_loop carry from the first
+        call."""
         if self.mesh_shape == (1, 1):
             return state
-        from ..core.mesh import make_mesh, shard_batch
-        return shard_batch(make_mesh(*self.mesh_shape), state)
+        from ..core.mesh import make_mesh, shard_solve_state
+        return shard_solve_state(make_mesh(*self.mesh_shape), state)
 
     def _solve_fn(self, nb: int, problem: str):
         """Per-bucket compiled-step cache: one fused solve per

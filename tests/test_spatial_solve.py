@@ -7,6 +7,7 @@ import subprocess
 import sys
 import textwrap
 
+import numpy as np
 import pytest
 
 pytestmark = pytest.mark.slow      # subprocess + forced 4-device shard_map
@@ -139,3 +140,121 @@ def test_sparse_spatial_solve_matches_single_device():
     assert res["score_diff"] < 1e-4
     # per-device block really is (B, N/P, D)
     assert res["shard_shape"][1] == 24 // 4
+
+
+_CHILD_ROWS = textwrap.dedent("""
+    import os
+    os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
+    import json, re
+    import numpy as np
+    import jax, jax.numpy as jnp
+    from repro.core import (PolicyConfig, init_policy, random_graph_batch,
+                            solve, env)
+    from repro.core import inference
+    from repro.core.engine import get_solve_step
+    from repro.core.graphrep import DENSE
+    from repro.core.mesh import make_mesh, shard_rows, shard_solve_state
+
+    n, sp = 256, 4
+    adj = random_graph_batch("er", n, 1, seed=3, rho=0.05)
+    params = init_policy(jax.random.key(4), PolicyConfig(embed_dim=16))
+    mesh = make_mesh(1, sp)
+    out = {"problems": {}}
+    for problem in ("mvc", "maxcut", "mis", "mds"):
+        kw = dict(num_layers=2, multi_node=True, rep="dense",
+                  problem=problem)
+        one = solve(params, adj, **kw)
+        plain = solve(params, adj, engine="host", kernel="xla", **kw)
+        split = solve(params, shard_rows(mesh, adj), spatial=(1, sp), **kw)
+        out["problems"][problem] = {
+            name: {"solution": r.solution.astype(int).tolist(),
+                   "evals": r.policy_evals,
+                   "committed": r.nodes_committed.tolist()}
+            for name, r in (("one", one), ("plain", plain),
+                            ("split", split))}
+        out["problems"][problem]["feasible"] = bool(np.asarray(
+            env.checker(problem)(jnp.asarray(adj),
+                                 jnp.asarray(split.solution))).all())
+
+    # the carry and the compiled program hold N/sp rows per device
+    state = shard_solve_state(mesh, inference.init_solve_state(
+        DENSE, shard_rows(mesh, adj), "mvc"))
+    fn = get_solve_step(rep=DENSE, problem="mvc", num_layers=2,
+                        use_adaptive=True, spatial=(1, sp), donate=False)
+    compiled = fn.lower(params, state, jnp.int32(n)).compile()
+    text = compiled.as_text()
+    final, evals, _ = fn(params, state, jnp.int32(n))
+    out["final_shards"] = sorted({tuple(s.data.shape)
+                                  for s in final.adj.addressable_shards})
+    out["argument_bytes"] = compiled.memory_analysis().argument_size_in_bytes
+    out["whole_graph_arrays"] = len(re.findall(rf"f32\\[1,{n},{n}\\]", text))
+    out["collectives"] = sorted(set(re.findall(
+        r"= (\\S+) (?:all-gather|all-reduce|all-to-all|collective-permute"
+        r"|reduce-scatter)", text)))
+
+    # an adjacency not laid out by rows is placed by rows: what the
+    # solve's state init receives is split, never replicated
+    seen = []
+    init = inference.init_solve_state
+    inference.init_solve_state = lambda rep, a, p: seen.append(sorted(
+        {tuple(s.data.shape) for s in a.addressable_shards})) or init(
+            rep, a, p)
+    solve(params, adj, spatial=(1, sp), **kw)
+    solve(params, jax.device_put(adj, jax.devices()[0]), spatial=(1, sp),
+          **kw)
+    inference.init_solve_state = init
+    out["placed_shards"] = seen
+    laid = shard_rows(mesh, adj)
+    out["laid_out_kept"] = shard_rows(mesh, laid) is laid
+    print(json.dumps(out))
+""")
+
+
+@pytest.fixture(scope="module")
+def rows_child():
+    env = dict(os.environ, PYTHONPATH=os.path.join(REPO, "src"))
+    env.pop("JAX_PLATFORMS", None)
+    out = subprocess.run([sys.executable, "-c", _CHILD_ROWS],
+                         capture_output=True, text=True, env=env,
+                         timeout=900)
+    assert out.returncode == 0, out.stderr[-3000:]
+    return json.loads(out.stdout.strip().splitlines()[-1])
+
+
+@pytest.mark.parametrize("problem", ["mvc", "maxcut", "mis", "mds"])
+def test_row_split_dense_solve_matches_one_device_and_plain_reference(
+        rows_child, problem):
+    """At N=256 over 4 devices the row-split solve (each device holding
+    and rewriting its 64 rows) gives the one-device fused solve's and the
+    host engine's unfused f32 chain's solutions, evaluation counts and
+    commit counts, and a feasible answer."""
+    r = rows_child["problems"][problem]
+    assert r["feasible"]
+    for ref in ("one", "plain"):
+        assert r["split"]["solution"] == r[ref]["solution"], ref
+        assert r["split"]["evals"] == r[ref]["evals"], ref
+        assert r["split"]["committed"] == r[ref]["committed"], ref
+
+
+def test_row_split_solve_carry_and_program_hold_a_quarter_of_the_graph(
+        rows_child):
+    n, sp = 256, 4
+    adj_bytes = 4 * n * n
+    # every device's shard of the returned state's adjacency: (B, N/sp, N)
+    assert rows_child["final_shards"] == [[1, n // sp, n]]
+    # arguments per device: its rows plus the (B, N) masks and weights
+    assert adj_bytes / sp <= rows_child["argument_bytes"] \
+        < 1.1 * adj_bytes / sp
+    # no array of the whole graph, and no collective moves rows: each is
+    # of (B, K, N) partials, (B, N) vectors or smaller
+    assert rows_child["whole_graph_arrays"] == 0
+    for shape in rows_child["collectives"]:
+        dims = [int(d) for d in shape.split("[")[1].split("]")[0].split(",")
+                if d]
+        assert np.prod(dims) <= 16 * n, shape
+
+
+def test_an_adjacency_not_laid_out_by_rows_is_placed_by_rows(rows_child):
+    # a host array and a one-device array each reach the state init split
+    assert rows_child["placed_shards"] == [[[1, 64, 256]], [[1, 64, 256]]]
+    assert rows_child["laid_out_kept"]

@@ -27,12 +27,14 @@ SHAPES = {
     "train": dict(b=64, n=1024, d=174, e=8032),
     "w1": dict(b=1, n=21_000),
     "w1_sp4": dict(b=1, nl=5_250, n=21_000),
+    "er64k_sp4": dict(b=1, nl=16_000, n=64_000),
     "ba16k": dict(b=1, n=16_384, d=1_089, e=326_208),
 }
 SHAPED = [("dense_fused", "train"), ("mp_aggregate", "train"),
           ("sparse_fused", "train"), ("gather", "train"),
           ("csr_fused", "train"), ("dense_fused", "w1"),
-          ("mp_aggregate", "w1_sp4"), ("sparse_fused", "ba16k"),
+          ("mp_aggregate", "w1_sp4"), ("mp_aggregate", "er64k_sp4"),
+          ("sparse_fused", "ba16k"),
           ("gather", "ba16k"), ("csr_fused", "ba16k")]
 # every kernel in f32; bf16 where the kernel takes a compute dtype (the
 # gather kernel is f32-only)
@@ -41,12 +43,11 @@ CASES = [(k, s, c) for k, s in SHAPED for c in ("f32", "bf16")
 
 
 @pytest.fixture(scope="module")
-def one_chip():
-    """One device of a described v5e:2x2 host, with the persistent
-    compilation cache off (entries written here cannot be read back)."""
+def v5e_2x2():
+    """A described v5e:2x2 host, with the persistent compilation cache off
+    (entries written here cannot be read back)."""
     from jax.experimental import topologies
     from jax.experimental.compilation_cache import compilation_cache
-    from jax.sharding import SingleDeviceSharding
     with pytest.MonkeyPatch.context() as mp:
         mp.setenv("TPU_LOG_DIR", "disabled")
         try:
@@ -58,9 +59,16 @@ def one_chip():
         jax.config.update("jax_enable_compilation_cache", False)
         compilation_cache.reset_cache()
         try:
-            yield SingleDeviceSharding(topo.devices[0])
+            yield topo
         finally:
             jax.config.update("jax_enable_compilation_cache", was)
+
+
+@pytest.fixture(scope="module")
+def one_chip(v5e_2x2):
+    """One device of the described v5e:2x2 host."""
+    from jax.sharding import SingleDeviceSharding
+    return SingleDeviceSharding(v5e_2x2.devices[0])
 
 
 def _spec(sharding, shape, dtype=jnp.float32):
@@ -151,8 +159,9 @@ def test_size_rule_matches_compiler(one_chip, kernel, rep, compute, axis):
 def test_dense_kernels_fit_at_every_n():
     """The dense kernels take the blocks that ``dense_tiles`` picks from
     K, N and Nl within a budget inside the limit, so the rule, counting
-    those same blocks, admits them at the train, W1 and W1-sp4 shapes."""
-    for shape in ("train", "w1", "w1_sp4"):
+    those same blocks, admits them at the train, W1, W1-sp4 and
+    ER-64k-sp4 shapes."""
+    for shape in ("train", "w1", "w1_sp4", "er64k_sp4"):
         n = SHAPES[shape]["n"]
         nl = SHAPES[shape].get("nl", n)
         for cd in (jnp.float32, jnp.bfloat16):
@@ -179,3 +188,51 @@ def test_w1_dense_kernel_reads_the_adjacency_unpadded(one_chip):
     adj = operands.split(", ")[2]
     shape = re.search(rf"^\s*{re.escape(adj)} = (\S+) ", text, re.M)
     assert shape and shape.group(1).startswith(f"f32[1,{n},{n}]")
+
+
+def test_er64k_row_split_solve_holds_its_rows_only(v5e_2x2, monkeypatch):
+    """The dense solve of the four-chip cell (ER N=64,000 on a (1, 4)
+    mesh) compiled for the described v5e 2x2 host: each chip's arguments
+    are its 16,000 adjacency rows (a quarter of the 16.4 GB graph) and the
+    masks, the donated rows carry the loop in place, no array of the whole
+    graph exists, and the one kernel, ``mp_aggregate``, reads the rows as
+    they are: no copy or relayout of the rows anywhere."""
+    import numpy as np
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+    from repro.core import engine, s2v
+    from repro.core.graphs import GraphState
+    from repro.core.policy import PolicyConfig, init_policy
+    from repro.kernels import backend
+    n, sp = 64_000, 4
+    mesh = Mesh(np.array(v5e_2x2.devices).reshape(1, sp), ("data", "graph"))
+    spec = lambda shape, ps, dt=jnp.float32: jax.ShapeDtypeStruct(
+        shape, dt, sharding=NamedSharding(mesh, ps))
+    params = jax.tree.map(lambda x: spec(x.shape, P(), x.dtype), init_policy(
+        jax.random.key(0), PolicyConfig(embed_dim=K)))
+    state = GraphState(adj=spec((1, n, n), P("data", "graph", None)),
+                       candidate=spec((1, n), P("data")),
+                       solution=spec((1, n), P("data")))
+    monkeypatch.setattr(backend, "on_tpu", lambda: True)
+    monkeypatch.setattr(s2v, "on_tpu", lambda: True)
+    monkeypatch.setattr(engine, "make_mesh", lambda dp, sp: mesh)
+    engine._build_solve_step.cache_clear()
+    try:
+        fn = engine.get_solve_step(rep="dense", problem="mvc", num_layers=2,
+                                   use_adaptive=True, spatial=(1, sp),
+                                   max_d=8, donate=True)
+        compiled = fn.lower(params, state,
+                            spec((), P(), jnp.int32)).compile()
+    finally:
+        engine._build_solve_step.cache_clear()
+    text = compiled.as_text()
+    mem = compiled.memory_analysis()
+    rows = 4 * n * n // sp
+    assert rows <= mem.argument_size_in_bytes < rows + (1 << 20)
+    assert mem.alias_size_in_bytes >= rows
+    assert mem.temp_size_in_bytes < 16 << 20
+    assert f"f32[1,{n},{n}]" not in text
+    block = re.escape(f"f32[1,{n // sp},{n}]")
+    assert not re.search(rf"= {block}\S* (copy|transpose|bitcast-convert)\(",
+                         text)
+    calls = re.findall(r"(%[\w.]+) = \S+ custom-call\(", text)
+    assert len(calls) == 1 and calls[0].startswith("%mp_aggregate."), calls
